@@ -60,7 +60,7 @@ def pallas_eqn_stats(eqn) -> dict:
         steps *= g
     vmem = hbm = 0
     for bm in gm.block_mappings:
-        sds = bm.array_shape_dtype
+        sds = bm.array_aval
         isz = sds.dtype.itemsize
         vmem += _block_elems(bm.block_shape) * isz
         full = 1
@@ -71,9 +71,11 @@ def pallas_eqn_stats(eqn) -> dict:
     body = getattr(kj, "jaxpr", kj)
     flops = (kernel_flops(body) * steps
              if hasattr(body, "eqns") else 0.0)
-    nsi = eqn.params.get("name_and_src_info")
+    # an explicit ``pallas_call(name=...)`` wins; else the kernel function
+    name = eqn.params.get("name") or getattr(
+        getattr(kj, "debug_info", None), "func_name", None)
     return {
-        "kernel": getattr(nsi, "name", None) or str(nsi),
+        "kernel": name or "pallas_call",
         "grid": grid, "grid_steps": steps,
         "vmem_bytes": vmem, "hbm_bytes": hbm, "flops": flops,
         "arithmetic_intensity": round(flops / hbm, 3) if hbm else 0.0,

@@ -65,7 +65,7 @@ def _seeded_collective_trace() -> TraceBundle:
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.utils.compat import shard_map
 
     mesh = jax.make_mesh((jax.device_count(),), ("data",))
     f = shard_map(lambda x: lax.pmean(x, "data"), mesh=mesh,

@@ -56,7 +56,7 @@ def custom_vjp_fwd_jaxprs(eqn) -> List[Any]:
         return []
     n_primal = len(eqn.invars) - int(eqn.params.get("num_consts", 0))
     try:
-        res = thunk(*([False] * max(n_primal, 0)))
+        res = thunk.call_wrapped(*([False] * max(n_primal, 0)))
     except Exception:  # noqa: BLE001 — un-traceable thunk: skip, don't fail
         return []
     jx = res[0] if isinstance(res, (tuple, list)) and res else res

@@ -32,7 +32,6 @@ from jax import lax
 from repro.core.comm import wire
 from repro.core.comm.wire import _bucket_len
 from repro.core.quantizers import Quantizer
-from repro.utils import compat
 
 
 def _names(axis_names) -> Tuple[str, ...]:
@@ -61,7 +60,7 @@ def _names(axis_names) -> Tuple[str, ...]:
 def axis_size(axis_names) -> int:
     n = 1
     for a in _names(axis_names):
-        n *= compat.axis_size(a)
+        n *= lax.axis_size(a)
     return n
 
 
@@ -86,6 +85,35 @@ def _chunk_spans(n_rows: int, k) -> list:
     return spans
 
 
+def _bucket_rows(parts: jnp.ndarray, d_eff: int) -> jnp.ndarray:
+    """(L, chunk) -> (L * nbc, d_eff) bucket rows, nbc = ceil(chunk/d_eff):
+    each part zero-padded to nbc*d_eff. Built from 1-D slices: the TPU
+    compiler turns a row pad of a long 2-D (L, chunk) array into a
+    relayout that takes minutes to compile at lm-100m size (~100 s for
+    110M elements, against ~1 s this way)."""
+    L, chunk = parts.shape
+    width = -(-chunk // d_eff) * d_eff
+    flat = parts.reshape(-1)
+    if width != chunk:
+        z = jnp.zeros((width - chunk,), parts.dtype)
+        flat = jnp.concatenate([
+            piece for i in range(L)
+            for piece in (flat[i * chunk:(i + 1) * chunk], z)])
+    return flat.reshape(-1, d_eff)
+
+
+def _unbucket_rows(rows: jnp.ndarray, L: int, chunk: int) -> jnp.ndarray:
+    """Inverse of :func:`_bucket_rows`: (L * nbc, d_eff) rows, or the same
+    as (L, nbc, d_eff) -> (L * chunk,) with each part's padding dropped,
+    from 1-D slices for the same reason."""
+    flat = rows.reshape(-1)
+    width = flat.shape[0] // L
+    if width == chunk:
+        return flat
+    return jnp.concatenate(
+        [flat[i * width:i * width + chunk] for i in range(L)])
+
+
 def _rs_mean_parts(parts, valid, qz: Quantizer, key, names, use_kernels,
                    pipeline_chunks: int = 1):
     """parts (L, chunk) local contributions, one row per destination worker;
@@ -105,13 +133,9 @@ def _rs_mean_parts(parts, valid, qz: Quantizer, key, names, use_kernels,
     the span's own shape would change them)."""
     L, chunk = parts.shape
     d_eff = _bucket_len(chunk, qz.bucket_size)
-    pad = -(-chunk // d_eff) * d_eff - chunk
-    parts = jnp.pad(parts.astype(jnp.float32), ((0, 0), (0, pad)))
-    valid = jnp.pad(valid, ((0, 0), (0, pad)))
-    nbc = parts.shape[1] // d_eff
-
-    bkt = parts.reshape(L * nbc, d_eff)
-    mask = valid.reshape(L * nbc, d_eff)
+    bkt = _bucket_rows(parts.astype(jnp.float32), d_eff)
+    mask = _bucket_rows(valid, d_eff)
+    nbc = bkt.shape[0] // L
     spans = _chunk_spans(nbc, pipeline_chunks)
     if len(spans) == 1:
         words, levels = wire.encode(qz, bkt, mask, key,
@@ -223,16 +247,13 @@ def local_qdq_comm_layout(
     chunk = -(-n // L)
     padded = jnp.pad(flat.astype(jnp.float32), (0, L * chunk - n))
     d_eff = _bucket_len(chunk, qz.bucket_size)
-    pad2 = -(-chunk // d_eff) * d_eff - chunk
-    parts = jnp.pad(padded.reshape(L, chunk), ((0, 0), (0, pad2)))
-    valid = jnp.pad(_valid_parts(valid, n, L, chunk), ((0, 0), (0, pad2)))
-    bkt = parts.reshape(-1, d_eff)
-    mask = valid.reshape(-1, d_eff)
+    bkt = _bucket_rows(padded.reshape(L, chunk), d_eff)
+    mask = _bucket_rows(_valid_parts(valid, n, L, chunk), d_eff)
     if worker_id is None:
         worker_id = lax.axis_index(names)
     key = jax.random.fold_in(key, worker_id)
     vals = wire.qdq(qz, bkt, mask, key, use_kernels=use_kernels)
-    return vals.reshape(L, -1)[:, :chunk].reshape(-1)[:n]
+    return _unbucket_rows(vals, L, chunk)[:n]
 
 
 def quantized_all_reduce_mean(
@@ -303,8 +324,7 @@ def quantized_all_reduce_mean(
             parts.append(wire.decode_each(qz, sw, sl, d_eff,
                                           use_kernels=use_kernels))
         vals = jnp.concatenate(parts, axis=1)             # (L, nbc, d_eff)
-    vals = vals.reshape(L, -1)[:, :chunk]
-    return vals.reshape(-1)[:n].astype(flat.dtype)
+    return _unbucket_rows(vals, L, chunk)[:n].astype(flat.dtype)
 
 
 def psum_mean_tree(tree, axis_names):

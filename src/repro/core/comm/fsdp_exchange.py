@@ -236,12 +236,17 @@ class FsdpLayout:
                 jnp.moveaxis(leaves[i].astype(compute_dtype),
                              self.slots[i].dim, 0).reshape(-1)
                 for i in g.leaf_ids])
-            rows = lax.all_gather(row, names, axis=0, tiled=False)  # (L, .)
+            # worker-major (L * width,); every leaf is cut from it with
+            # 1-D slices (see collectives._bucket_rows for why not 2-D)
+            rows = lax.all_gather(row, names, axis=0, tiled=True)
+            width = row.shape[0]
             for i in g.leaf_ids:
                 s = self.slots[i]
                 shard = s.size // L
                 rest = s.shape[:s.dim] + s.shape[s.dim + 1:]
-                seg = rows[:, s.offset:s.offset + shard]
+                seg = jnp.concatenate([
+                    rows[w * width + s.offset:w * width + s.offset + shard]
+                    for w in range(L)])
                 seg = seg.reshape((s.shape[s.dim],) + rest)
                 full[i] = jnp.moveaxis(seg, 0, s.dim)
         return jax.tree_util.tree_unflatten(self.treedef, full)
@@ -260,11 +265,13 @@ class FsdpLayout:
                     [leaves[i].astype(jnp.float32).reshape(-1)
                      for i in g.leaf_ids]))
                 continue
-            rows = jnp.concatenate([
-                jnp.moveaxis(leaves[i].astype(jnp.float32),
-                             self.slots[i].dim, 0).reshape(L, -1)
-                for i in g.leaf_ids], axis=1)
-            bufs.append(rows.reshape(-1))
+            flat = [jnp.moveaxis(leaves[i].astype(jnp.float32),
+                                 self.slots[i].dim, 0).reshape(-1)
+                    for i in g.leaf_ids]
+            sizes = [self.slots[i].size // L for i in g.leaf_ids]
+            bufs.append(jnp.concatenate([
+                f[w * n:(w + 1) * n] for w in range(L)
+                for f, n in zip(flat, sizes)]))
         return tuple(bufs)
 
     def unflatten_outputs(self, outs: Sequence[jnp.ndarray], *,

@@ -19,7 +19,6 @@ from jax import lax
 from repro.core.comm.collectives import _names, quantized_all_reduce_mean
 from repro.core.comm.fsdp_exchange import reduce_scatter_mean_block
 from repro.core.quantizers import Quantizer
-from repro.utils import compat
 from repro.utils.compat import shard_map
 
 
@@ -72,10 +71,7 @@ def make_fsdp_gather(
     def bwd(res, g):
         key, wid = res
         key_w = jax.random.fold_in(key, wid)
-        # Legacy JAX cannot nest a manual region over the tp axis inside
-        # the dp-manual region; fall back to the direct path (XLA then
-        # partitions the flatten itself — slower, still correct).
-        if tp_dim is not None and compat.supports_nested_manual():
+        if tp_dim is not None:
             spec = [None] * g.ndim
             spec[tp_dim] = tp_axis
             pspec = jax.sharding.PartitionSpec(*spec)

@@ -28,25 +28,29 @@ def packed_words(d: int, bits: int) -> int:
 
 
 def pack(idx: jnp.ndarray, bits: int) -> jnp.ndarray:
-    """(nb, d) int32 indices in [0, 2^bits) -> (nb, nw) uint32 words."""
+    """(nb, d) int32 indices in [0, 2^bits) -> (nb, nw) uint32 words.
+
+    Slice layout: column c goes to word ``c % nw`` at bit offset
+    ``bits * (c // nw)`` — bit field j of every word holds the contiguous
+    column slice ``[j*nw, (j+1)*nw)`` (zero-padded past d)."""
     nb, d = idx.shape
     epw = elems_per_word(bits)
     nw = packed_words(d, bits)
     padded = jnp.pad(idx.astype(jnp.uint32), ((0, 0), (0, nw * epw - d)))
-    lanes = padded.reshape(nb, nw, epw)
-    shifts = (jnp.arange(epw, dtype=jnp.uint32) * jnp.uint32(bits))[None, None, :]
+    fields = padded.reshape(nb, epw, nw)
+    shifts = (jnp.arange(epw, dtype=jnp.uint32) * jnp.uint32(bits))[None, :, None]
     # disjoint bit ranges: addition == bitwise OR
-    return (lanes << shifts).sum(axis=-1, dtype=jnp.uint32)
+    return (fields << shifts).sum(axis=1, dtype=jnp.uint32)
 
 
 def unpack(words: jnp.ndarray, bits: int, d: int) -> jnp.ndarray:
-    """(nb, nw) uint32 -> (nb, d) int32 indices."""
+    """(nb, nw) uint32 -> (nb, d) int32 indices (inverse of :func:`pack`)."""
     nb, nw = words.shape
     epw = elems_per_word(bits)
-    shifts = (jnp.arange(epw, dtype=jnp.uint32) * jnp.uint32(bits))[None, None, :]
+    shifts = (jnp.arange(epw, dtype=jnp.uint32) * jnp.uint32(bits))[None, :, None]
     mask = jnp.uint32(2 ** bits - 1)
-    lanes = (words[:, :, None] >> shifts) & mask
-    return lanes.reshape(nb, nw * epw)[:, :d].astype(jnp.int32)
+    fields = (words[:, None, :] >> shifts) & mask
+    return fields.reshape(nb, epw * nw)[:, :d].astype(jnp.int32)
 
 
 def wire_bits(n_elems: int, n_buckets: int, s: int) -> Tuple[float, float]:

@@ -17,12 +17,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-_INV_U32 = jnp.float32(1.0 / 4294967296.0)  # 2**-32
-
-
-def uniform_from_bits(bits: jnp.ndarray) -> jnp.ndarray:
-    """uint32 -> [0, 1) float32 (multiplicative, matches kernel)."""
-    return bits.astype(jnp.float32) * _INV_U32
+# uint32 -> [0, 1): the single rule the kernels and the oracle share
+from repro.kernels.ref import uniform_from_bits
 
 
 def find_interval(bkt: jnp.ndarray, levels: jnp.ndarray) -> jnp.ndarray:

@@ -1,9 +1,11 @@
 """Pallas TPU kernel: bit-pack level indices into uint32 wire words.
 
-Packs ``epw = 32 // bits`` consecutive indices into each uint32 word via
-shift-add (disjoint bit ranges, so addition == OR — avoids any reliance on
-integer OR reductions). Unpack is the mirror shift-mask. These run just
-before/after the all_to_all so the wire payload is the packed words.
+Packs ``epw = 32 // bits`` indices into each uint32 word via shift-add
+(disjoint bit ranges, so addition == OR — avoids any reliance on integer
+OR reductions) in the wire's slice layout: column c sits in word
+``c % nw`` at bit offset ``bits * (c // nw)``. Unpack is the mirror
+shift-mask. These run just before/after the all_to_all so the wire
+payload is the packed words.
 """
 from __future__ import annotations
 
@@ -13,17 +15,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fused_encode import _pack_words
+
 ROW_BLOCK = 8
 
 
 def _pack_kernel(bits: int, epw: int, idx_ref, out_ref):
-    idx = idx_ref[...].astype(jnp.uint32)          # (R, nw*epw)
-    r, n = idx.shape
-    lanes = idx.reshape(r, n // epw, epw)
-    acc = jnp.zeros((r, n // epw), dtype=jnp.uint32)
-    for j in range(epw):                            # static unroll
-        acc = acc + (lanes[:, :, j] << jnp.uint32(bits * j))
-    out_ref[...] = acc
+    out_ref[...] = _pack_words(idx_ref[...], bits, epw)   # (R, nw*epw)
 
 
 def _unpack_kernel(bits: int, epw: int, w_ref, out_ref):
@@ -32,7 +30,7 @@ def _unpack_kernel(bits: int, epw: int, w_ref, out_ref):
     parts = []
     for j in range(epw):                            # static unroll
         parts.append(((w >> jnp.uint32(bits * j)) & mask).astype(jnp.int32))
-    out_ref[...] = jnp.stack(parts, axis=-1).reshape(out_ref.shape)
+    out_ref[...] = jnp.concatenate(parts, axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))

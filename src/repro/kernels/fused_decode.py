@@ -16,9 +16,11 @@ tensor never exists in HBM (a 32/bits traffic shrink on the decode side):
                         worker reconstructs each server's re-quantized
                         chunk -> (L, nb, d) values, no averaging.
 
-Word lane order matches the multi-pass ``bitpack.unpack`` kernel; the
-one-hot decode matches ``dequant_avg``, so interpret mode is bit-identical
-to both the multi-pass kernels and the jnp oracles in ``ref.py``.
+Words are in the wire's slice layout (element c in word ``c % nw`` at
+bit offset ``bits * (c // nw)``), as in the multi-pass ``bitpack.unpack``
+kernel; the one-hot decode matches ``dequant_avg``, so interpret mode is
+bit-identical to both the multi-pass kernels and the jnp oracles in
+``ref.py``.
 """
 from __future__ import annotations
 
@@ -40,7 +42,7 @@ def _unpack_decode(w: jnp.ndarray, lv: jnp.ndarray, s: int, bits: int,
     parts = []
     for j in range(epw):                          # static unroll
         parts.append(((w >> jnp.uint32(bits * j)) & mask).astype(jnp.int32))
-    idx = jnp.stack(parts, axis=-1).reshape(w.shape[0], w.shape[1], -1)
+    idx = jnp.concatenate(parts, axis=-1)        # element c = j*nw + word
     val = jnp.zeros(idx.shape, dtype=jnp.float32)
     for j in range(s):                  # static unroll, gather-free decode
         val = val + (idx == j).astype(jnp.float32) * lv[:, :, j][:, :, None]

@@ -57,13 +57,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.ref import uniform_from_bits
 
 ROW_BLOCK = 8   # row-block quantum (f32 sublane tile)
+_LANES = 128    # lane tile
 LEVEL_PAD = 32  # level-table tile width (s <= 17 always)
 #: VMEM budget per grid-step tile (all operands + outputs). Kept well
 #: under the ~16 MB/core VMEM so double-buffered in/out windows fit.
 VMEM_TILE_BYTES = 2 * 1024 * 1024
-_INV_U32 = float(1.0 / 4294967296.0)
 
 #: rounding modes the fused stage understands
 MODES = ("rr", "bin", "sign")
@@ -145,21 +148,28 @@ def _clip_round(s: int, mode: str, v: jnp.ndarray, lv: jnp.ndarray,
 
 
 def _pack_words(idx: jnp.ndarray, bits: int, epw: int) -> jnp.ndarray:
-    """(R, d) int32 -> (R, ceil(d/epw)) uint32 shift-add pack (add == OR
-    on disjoint bit ranges; same lane order as the multi-pass pack
-    kernel). The ragged tail is zero-padded IN-REGISTER — padding the
-    kernel INPUTS instead would widen the row reductions (the BinGrad
-    conditional means) and shift their rounding by an ulp vs the jnp
-    oracle."""
+    """(R, d) int32 -> (R, nw) uint32, nw = ceil(d/epw), shift-add pack
+    (add == OR on disjoint bit ranges) in the wire's slice layout: column
+    c goes to word ``c % nw`` at bit offset ``bits * (c // nw)``, so bit
+    field j is the column slice ``[j*nw, (j+1)*nw)``. The TPU's Pallas
+    compiler refuses the lane-splitting reshape an interleaved layout
+    needs, and silently miscompiles lane slices that start off a
+    128-lane boundary, so each field is rotated down to lane 0
+    (``pltpu.roll``) and sliced from there. The ragged tail is zero-padded
+    IN-REGISTER, to a whole number of lane tiles — padding the kernel
+    INPUTS instead would widen the row reductions (the BinGrad conditional
+    means) and shift their rounding by an ulp vs the jnp oracle."""
     r, d = idx.shape
-    dp = -(-d // epw) * epw
-    if dp != d:
+    nw = -(-d // epw)
+    width = -(-nw * epw // _LANES) * _LANES
+    if width != d:
         idx = jnp.concatenate(
-            [idx, jnp.zeros((r, dp - d), dtype=idx.dtype)], axis=-1)
-    lanes = idx.astype(jnp.uint32).reshape(r, dp // epw, epw)
-    acc = jnp.zeros((r, dp // epw), dtype=jnp.uint32)
-    for j in range(epw):                          # static unroll
-        acc = acc + (lanes[:, :, j] << jnp.uint32(bits * j))
+            [idx, jnp.zeros((r, width - d), dtype=idx.dtype)], axis=-1)
+    idx = idx.astype(jnp.uint32)
+    acc = idx[:, :nw]
+    for j in range(1, epw):                       # static unroll
+        field = pltpu.roll(idx, width - j * nw, 1)[:, :nw]
+        acc = acc + (field << jnp.uint32(bits * j))
     return acc
 
 
@@ -169,7 +179,7 @@ def _encode_kernel(s, bits, epw, has_lim, mode, *refs):
     rest = refs[3:]
     lim = rest.pop(0)[...] if has_lim else None
     if mode == "rr":
-        u = rest.pop(0)[...].astype(jnp.float32) * _INV_U32
+        u = uniform_from_bits(rest.pop(0)[...])
     else:
         u = None
     (w_ref,) = rest
@@ -186,7 +196,7 @@ def _qdq_kernel(s, has_lim, mode, *refs):
     rest = refs[3:]
     lim = rest.pop(0)[...] if has_lim else None
     if mode == "rr":
-        u = rest.pop(0)[...].astype(jnp.float32) * _INV_U32
+        u = uniform_from_bits(rest.pop(0)[...])
     else:
         u = None
     (o_ref,) = rest

@@ -18,8 +18,8 @@ Two hot paths, one ``pallas_call`` each:
                    sequence — the dequantized (C, d) K/V tensors never
                    round-trip HBM. The kernel body calls
                    ``ref.kv_attend_block`` on its tile, the SAME function
-                   the jnp oracle (``ref.kv_attend_ref``) vmaps over the
-                   batch, so kernel/oracle bit-identity holds by
+                   the jnp oracle (``ref.kv_attend_ref``) runs once per
+                   sequence, so kernel/oracle bit-identity holds by
                    construction.
 
 Dispatch (env overrides, ``REPRO_USE_KERNELS=0`` oracle leg) lives in
@@ -33,18 +33,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref as _ref
 
+#: scoped-VMEM limit of one ``decode_attend`` program. Each program holds
+#: its sequence's whole dequantized (C, d) K and V plus their unpack
+#: temporaries: lm-100m (d=768) at C=1024 needs ~26 MiB, over the
+#: compiler's 16 MiB default. 64 MiB (half of a v5e core's 128 MiB VMEM)
+#: covers C up to ~2K; longer contexts need the kernel to tile C.
+ATTEND_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
-def _attend_kernel(bits, kv_heads, hd, scale, softcap, T, H,
+
+def _attend_kernel(bits, kv_heads, hd, scale, softcap,
                    q_ref, kw_ref, klv_ref, vw_ref, vlv_ref, m_ref, o_ref):
-    q = q_ref[...][0].reshape(T, H, hd)
-    out = _ref.kv_attend_block(
-        q, kw_ref[...][0], klv_ref[...][0], vw_ref[...][0], vlv_ref[...][0],
-        m_ref[...][0], bits=bits, kv_heads=kv_heads, scale=scale,
+    o_ref[0] = _ref.kv_attend_block(
+        q_ref[0], kw_ref[0], klv_ref[0], vw_ref[0], vlv_ref[0], m_ref[0],
+        bits=bits, kv_heads=kv_heads, head_dim=hd, scale=scale,
         softcap=softcap)
-    o_ref[...] = out.reshape(1, T, H * hd)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "kv_heads", "scale",
@@ -69,7 +75,7 @@ def decode_attend(q: jnp.ndarray, kw: jnp.ndarray, klv: jnp.ndarray,
     mf = mask.astype(jnp.float32)
     out = pl.pallas_call(
         functools.partial(_attend_kernel, bits, kv_heads, hd, scale,
-                          softcap, T, H),
+                          softcap),
         out_shape=jax.ShapeDtypeStruct((B, T, H * hd), jnp.float32),
         grid=(B,),
         in_specs=[
@@ -81,6 +87,8 @@ def decode_attend(q: jnp.ndarray, kw: jnp.ndarray, klv: jnp.ndarray,
             pl.BlockSpec((1, T, C), lambda b: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, T, H * hd), lambda b: (b, 0, 0)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=ATTEND_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(q2, kw, klv.astype(jnp.float32), vw, vlv.astype(jnp.float32), mf)
     return out.reshape(B, T, H, hd)

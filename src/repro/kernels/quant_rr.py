@@ -21,15 +21,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import uniform_from_bits
+
 ROW_BLOCK = 8
 LEVEL_PAD = 32  # level-table tile width (s <= 17 always)
-_INV_U32 = float(1.0 / 4294967296.0)
 
 
 def _quant_rr_kernel(s: int, v_ref, lv_ref, bits_ref, idx_ref):
     v = v_ref[...].astype(jnp.float32)          # (R, d)
     lv = lv_ref[...].astype(jnp.float32)        # (R, LEVEL_PAD)
-    u = bits_ref[...].astype(jnp.float32) * _INV_U32
+    u = uniform_from_bits(bits_ref[...])
 
     # interval search: k = (#levels <= v) - 1, clipped to [0, s-2]
     k = jnp.zeros(v.shape, dtype=jnp.int32)

@@ -13,7 +13,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-_INV_U32 = jnp.float32(1.0 / 4294967296.0)
+
+def uniform_from_bits(bits: jnp.ndarray) -> jnp.ndarray:
+    """uint32 rounding bits -> [0, 1) float32: the top 24 bits as an
+    int32, times 2^-24. The one conversion rule of every random-round
+    path (oracle, multi-pass and fused kernels, ``rounding``). It is exact
+    (every value is an f32) and lowers on the TPU, whose Pallas compiler
+    has no uint32 -> float32 cast."""
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(2.0 ** -24)
 
 
 def quant_rr_ref(v: jnp.ndarray, levels: jnp.ndarray,
@@ -30,8 +38,7 @@ def quant_rr_ref(v: jnp.ndarray, levels: jnp.ndarray,
     width = hi - lo
     p_up = jnp.where(width > 0, (vc - lo) / jnp.where(width > 0, width, 1.0),
                      0.0)
-    u = bits.astype(jnp.float32) * _INV_U32
-    return k + (u < p_up).astype(jnp.int32)
+    return k + (uniform_from_bits(bits) < p_up).astype(jnp.int32)
 
 
 def bingrad_pass_ref(v: jnp.ndarray, b0: jnp.ndarray, mask: jnp.ndarray):
@@ -131,6 +138,9 @@ def encode_bingrad_fused_ref(v: jnp.ndarray, mask: jnp.ndarray, *,
 # ---------------------------------------------------------------------------
 
 _NEG_INF = -2.0e38
+#: full-f32 matmuls: XLA's default on the TPU is one bf16 pass, which
+#: would set the oracle apart from the kernel by ~1e-3
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _kv_decode(w: jnp.ndarray, lv: jnp.ndarray, bits: int, s: int,
@@ -143,7 +153,7 @@ def _kv_decode(w: jnp.ndarray, lv: jnp.ndarray, bits: int, s: int,
     parts = []
     for j in range(epw):                          # static unroll
         parts.append(((w >> jnp.uint32(bits * j)) & m).astype(jnp.int32))
-    idx = jnp.stack(parts, axis=-1).reshape(w.shape[0], -1)[:, :d]
+    idx = jnp.concatenate(parts, axis=-1)[:, :d]  # element c = j*nw + word
     val = jnp.zeros(idx.shape, dtype=jnp.float32)
     for j in range(s):                  # static unroll, gather-free decode
         val = val + ((idx == j).astype(jnp.float32)
@@ -153,48 +163,60 @@ def _kv_decode(w: jnp.ndarray, lv: jnp.ndarray, bits: int, s: int,
 
 def kv_attend_block(q: jnp.ndarray, kw: jnp.ndarray, klv: jnp.ndarray,
                     vw: jnp.ndarray, vlv: jnp.ndarray, mask: jnp.ndarray, *,
-                    bits: int, kv_heads: int, scale: float,
+                    bits: int, kv_heads: int, head_dim: int, scale: float,
                     softcap: float = 0.0) -> jnp.ndarray:
-    """One sequence of fused dequant-attention: q (T, H, hd) against a
+    """One sequence of fused dequant-attention: q (T, H*hd) against a
     quantized KV context kw/vw (C, nw) uint32 + klv/vlv (C, s) levels with
-    mask (T, C) in {0, 1} -> (T, H, hd) f32.
+    mask (T, C) in {0, 1} -> (T, H*hd) f32.
 
     This is THE definition of the math: the Pallas kernel body in
     ``fused_kv.py`` calls this very function on its VMEM tile, and the
-    oracle ``kv_attend_ref`` vmaps it over the batch — bit-identity between
-    kernel and oracle is by construction, not by mirroring."""
-    T, H, hd = q.shape
-    d = kv_heads * hd
-    s = klv.shape[-1]
-    k = _kv_decode(kw, klv, bits, s, d).reshape(-1, kv_heads, hd)
-    v = _kv_decode(vw, vlv, bits, s, d).reshape(-1, kv_heads, hd)
+    oracle ``kv_attend_ref`` runs it once per sequence — bit-identity between
+    kernel and oracle is by construction, not by mirroring. Heads stay
+    lane slices of the flat rows (one 2-D matmul pair per query head): the
+    TPU's Pallas compiler refuses the (T, H*hd) -> (T, H, hd) reshape."""
+    hd = head_dim
+    H = q.shape[-1] // hd
     g = H // kv_heads
-    qg = q.astype(jnp.float32).reshape(T, kv_heads, g, hd)
-    sc = jnp.einsum("tkgh,ckh->kgtc", qg, k,
-                    preferred_element_type=jnp.float32) * scale
-    sc = sc.reshape(H, T, -1)
-    if softcap:
-        sc = jnp.tanh(sc / softcap) * softcap
-    sc = jnp.where(mask[None, :, :] > 0, sc, _NEG_INF)
-    p = jax.nn.softmax(sc, axis=-1)                       # (H, T, C)
-    o = jnp.einsum("kgtc,ckh->tkgh", p.reshape(kv_heads, g, T, -1), v,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(T, H, hd)
+    s = klv.shape[-1]
+    k = _kv_decode(kw, klv, bits, s, kv_heads * hd)
+    v = _kv_decode(vw, vlv, bits, s, kv_heads * hd)
+    q = q.astype(jnp.float32)
+    outs = []
+    for h in range(H):                            # static unroll
+        lo = (h // g) * hd                        # this head's KV columns
+        sc = jax.lax.dot_general(
+            q[:, h * hd:(h + 1) * hd], k[:, lo:lo + hd],
+            (((1,), (1,)), ((), ())), precision=_F32,
+            preferred_element_type=jnp.float32) * scale      # (T, C)
+        if softcap:
+            sc = jnp.tanh(sc / softcap) * softcap
+        sc = jnp.where(mask > 0, sc, _NEG_INF)
+        e = jnp.exp(sc - sc.max(axis=-1, keepdims=True))
+        p = e / e.sum(axis=-1, keepdims=True)
+        outs.append(jnp.dot(p, v[:, lo:lo + hd], precision=_F32,
+                            preferred_element_type=jnp.float32))
+    return jnp.concatenate(outs, axis=-1)
 
 
 def kv_attend_ref(q: jnp.ndarray, kw: jnp.ndarray, klv: jnp.ndarray,
                   vw: jnp.ndarray, vlv: jnp.ndarray, mask: jnp.ndarray, *,
                   bits: int, kv_heads: int, scale: float,
                   softcap: float = 0.0) -> jnp.ndarray:
-    """Oracle for kernels.fused_kv.decode_attend: vmap of
-    :func:`kv_attend_block` over the batch dim. q (B, T, H, hd), kw/vw
-    (B, C, nw), klv/vlv (B, C, s), mask (B, T, C) -> (B, T, H, hd) f32."""
-    import functools
-
-    fn = functools.partial(kv_attend_block, bits=bits, kv_heads=kv_heads,
-                           scale=scale, softcap=softcap)
-    return jax.vmap(fn)(q.astype(jnp.float32), kw, klv, vw, vlv,
-                        mask.astype(jnp.float32))
+    """Oracle for kernels.fused_kv.decode_attend: :func:`kv_attend_block`
+    once per sequence (unbatched, as each kernel program runs it — a vmap
+    would batch the matmuls and change their accumulation order). q
+    (B, T, H, hd), kw/vw (B, C, nw), klv/vlv (B, C, s), mask (B, T, C) ->
+    (B, T, H, hd) f32."""
+    B, T, H, hd = q.shape
+    q2 = q.astype(jnp.float32).reshape(B, T, H * hd)
+    mf = mask.astype(jnp.float32)
+    out = jnp.stack([
+        kv_attend_block(q2[b], kw[b], klv[b], vw[b], vlv[b], mf[b],
+                        bits=bits, kv_heads=kv_heads, head_dim=hd,
+                        scale=scale, softcap=softcap)
+        for b in range(B)])                       # static unroll
+    return out.reshape(B, T, H, hd)
 
 
 def decode_fused_mean_ref(words: jnp.ndarray, levels: jnp.ndarray, *,

@@ -32,11 +32,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config, get_smoke_config, list_archs
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import device_label, make_host_mesh
 from repro.models import LM
 from repro.serve import Engine, ServeConfig
 from repro.serve.step import (make_chunked_prefill_step, make_serve_step,
                               plan_serve_sharding)
+from repro.utils.env import use_compile_cache
 
 
 def _digest(toks: np.ndarray) -> str:
@@ -115,9 +116,9 @@ def _serve_dense(args, cfg, model, params, prompt):
           f"({'chunk ' + str(chunk) if chunk else 'decode loop'})")
     print(f"decode:  {dec_tok} tokens in {t2-t1:.2f}s = "
           f"{dec_tok/max(t2-t1, 1e-9):.1f} tok/s "
-          f"(host CPU, batch {args.batch})")
+          f"({device_label()}, batch {args.batch})")
     print("tokens sha256:", _digest(toks))
-    return 0
+    return toks
 
 
 def _serve_paged(args, cfg, model, params, prompt):
@@ -153,17 +154,19 @@ def _serve_paged(args, cfg, model, params, prompt):
           f"(chunk {scfg.prefill_chunk})")
     print(f"decode:  {eng.decode_tokens} tokens in {dec_s:.2f}s = "
           f"{eng.decode_tokens/max(dec_s, 1e-9):.1f} tok/s "
-          f"(kv={args.kv_quant}, batch {args.batch})")
+          f"(kv={args.kv_quant}, {device_label()}, batch {args.batch})")
     if len(lat):
         print(f"step latency p50 {np.percentile(lat, 50):.1f}ms "
               f"p99 {np.percentile(lat, 99):.1f}ms")
     print(f"cache bytes: {eng.cache_bytes()} "
           f"({eng.kvq.token_bytes()} per token-layer)")
     print("tokens sha256:", _digest(toks))
-    return 0
+    return toks
 
 
-def main(argv=None):
+def serve(argv=None) -> np.ndarray:
+    """Parse ``argv``, serve the batch and print the report; returns the
+    generated tokens, (batch, gen) int32."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="lm-100m", choices=list_archs())
     ap.add_argument("--smoke", action="store_true")
@@ -192,6 +195,12 @@ def main(argv=None):
     if args.kv_quant:
         return _serve_paged(args, cfg, model, params, np.asarray(prompt))
     return _serve_dense(args, cfg, model, params, prompt)
+
+
+def main(argv=None):
+    use_compile_cache()
+    serve(argv)
+    return 0
 
 
 if __name__ == "__main__":

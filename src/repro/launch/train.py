@@ -32,12 +32,13 @@ from repro.configs.base import get_config, get_smoke_config, list_archs
 from repro.core import (BitBudgetController, BitSchedule, QuantPolicy,
                         all_methods, comm)
 from repro.data import SyntheticLM
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import device_label, make_host_mesh
 from repro.models import LM
 from repro.optim.schedule import step_decay
 from repro.train import TrainConfig, make_train_step
 from repro.train.step import (ScheduledTrainStep, init_state,
                               specialize_engines)
+from repro.utils.env import use_compile_cache
 
 
 def _params_digest(params) -> str:
@@ -151,6 +152,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.checkpoint_at is not None and not args.state_checkpoint:
         ap.error("--checkpoint-at needs --state-checkpoint")
+    use_compile_cache()
 
     schedule = None
     if args.bit_schedule is not None:
@@ -245,6 +247,7 @@ def main(argv=None):
         start = int(state.step)
         print(f"resumed {args.resume} at step {start}")
     history = []
+    print(f"device: {device_label()}, mesh {dict(mesh.shape)}")
     t0 = time.time()
     for i in range(start, args.steps):
         batch = data.batch(i)

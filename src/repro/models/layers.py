@@ -18,23 +18,12 @@ def shard(x: jnp.ndarray, *spec):
     tests (1 device, no mesh), under jit+NamedSharding, and inside shard_map
     bodies with auto axes.
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:  # pragma: no cover - older jax
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
-    if mesh is None or not mesh.axis_names:
-        return x
-    avail = set(mesh.axis_names)
     # inside shard_map, manual axes cannot appear in constraints
-    try:
-        manual = {a for a in mesh.axis_names
-                  if mesh._name_to_type[a] == jax.sharding.AxisType.Manual}
-    except Exception:  # pragma: no cover
-        manual = set()
-    usable = avail - manual
-
-    sizes = dict(zip(mesh.axis_names, mesh.shape.values())) \
-        if hasattr(mesh.shape, "values") else dict(mesh.shape)
+    usable = set(mesh.axis_names) - set(mesh.manual_axes)
+    sizes = dict(mesh.shape)
 
     def _axes_size(entry) -> int:
         if isinstance(entry, (tuple, list)):

@@ -1,5 +1,6 @@
-"""Distributed train step: shard_map(manual=dp axes, auto=model) with the
-paper's quantized gradient exchange at the FSDP boundary.
+"""Distributed train step: shard_map(manual=dp axes, auto=model when
+model > 1, else manual over every axis) with the paper's quantized
+gradient exchange at the FSDP boundary.
 
 Layout (ZeRO-3):
   * every f32 master-param leaf is sharded over the combined dp axes
@@ -231,6 +232,14 @@ class ShardingPlan:
         paths = jax.tree_util.tree_leaves(self.paths)
         return {p: spec_dp_dim(s, self.dp_axes)
                 for p, s in zip(paths, specs)}
+
+
+def _manual_axes(mesh, plan: "ShardingPlan") -> Tuple[str, ...]:
+    """The train step's manual ``shard_map`` axes: every mesh axis, unless
+    tensor parallelism needs ``model`` left to the SPMD partitioner. The
+    exchange's Pallas kernels cannot be auto-partitioned, so a size-1
+    ``model`` axis left auto stops the step from compiling on a TPU."""
+    return tuple(mesh.axis_names) if plan.n_model == 1 else plan.dp_axes
 
 
 def _dp_axes(mesh) -> Tuple[str, ...]:
@@ -469,7 +478,8 @@ def init_state(model: LM, mesh, tcfg: TrainConfig, key) -> TrainState:
                           step=jnp.int32(0), ef=ef)
 
     if tcfg.mode == "replicated":
-        out_sh = None
+        # replicated over the whole mesh, not left on the default device
+        out_sh = NamedSharding(mesh, P())
         if h_async > 1:
             rep = NamedSharding(mesh, P())
             stk = NamedSharding(mesh, P(dp_ent))
@@ -886,7 +896,7 @@ def make_train_step(model: LM, mesh, tcfg: TrainConfig, lr_fn=None,
         fn = shard_map(local_step, mesh=mesh,
                        in_specs=(state_specs, batch_specs, P()),
                        out_specs=(state_specs, rep_metric_specs),
-                       axis_names=dp_axes, check_vma=False)
+                       axis_names=_manual_axes(mesh, plan), check_vma=False)
         return jax.jit(fn, donate_argnums=(0,)), plan
 
     # fsdp mode
@@ -907,7 +917,7 @@ def make_train_step(model: LM, mesh, tcfg: TrainConfig, lr_fn=None,
     fn = shard_map(local_step, mesh=mesh,
                    in_specs=(state_specs, batch_specs, P()),
                    out_specs=(state_specs, metric_specs),
-                   axis_names=dp_axes, check_vma=False)
+                   axis_names=_manual_axes(mesh, plan), check_vma=False)
     return jax.jit(fn, donate_argnums=(0,)), plan
 
 
@@ -1102,13 +1112,13 @@ def _make_async_train_step(model: LM, mesh, tcfg: TrainConfig, lr_fn,
         shard_map(inner_step, mesh=mesh,
                   in_specs=(state_specs, batch_specs, P()),
                   out_specs=(state_specs, inner_metric_specs),
-                  axis_names=dp_axes, check_vma=False),
+                  axis_names=_manual_axes(mesh, eng.plan), check_vma=False),
         donate_argnums=(0,))
     sync_fn = jax.jit(
         shard_map(sync_step, mesh=mesh,
                   in_specs=(state_specs, batch_specs, P()),
                   out_specs=(state_specs, sync_metric_specs),
-                  axis_names=dp_axes, check_vma=False),
+                  axis_names=_manual_axes(mesh, eng.plan), check_vma=False),
         donate_argnums=(0,))
     return AsyncTrainStep(inner_fn, sync_fn, tcfg.local_steps)
 

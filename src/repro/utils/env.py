@@ -21,6 +21,10 @@ Flags:
     tier-1 suite this way to enforce kernel/ref parity. ``1``/unset
     keeps the caller's flag (kernels by default).
 
+``JAX_COMPILATION_CACHE_DIR``
+    Where the entry points keep JAX's persistent compilation cache
+    (:func:`use_compile_cache`); unset, a fixed directory in the checkout.
+
 No jax import at module scope: :func:`force_host_device_count` must be
 callable BEFORE jax first initializes (device counts lock on first use).
 """
@@ -68,6 +72,27 @@ def kernels_enabled() -> bool:
     flag = env_flag("REPRO_USE_KERNELS",
                     context="to keep the caller's flag")
     return True if flag is None else flag
+
+
+#: the compile cache's fixed home when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: inside the checkout (listed in ``.gitignore``), never a temp
+#: name — the path is part of the cache key, so a moving one never hits
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. ``JAX_COMPILATION_CACHE_DIR``, where set, is
+    read by JAX itself and no other path is set here; otherwise the cache
+    lives at :data:`DEFAULT_COMPILE_CACHE_DIR`."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if path:
+        return path
+    import jax  # deferred: keep this module importable pre-jax-init
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+    return DEFAULT_COMPILE_CACHE_DIR
 
 
 def force_host_device_count(n: int, *, platform: str = "cpu") -> None:

@@ -160,9 +160,10 @@ class TestAttendParity:
 
         def dec(w, lv):
             w = np.asarray(w)
-            idx = np.stack([(w >> (bits * j)) & m for j in range(epw)],
-                           axis=-1)
-            idx = idx.reshape(B, C, -1)[:, :, :D].astype(np.int64)
+            # slice layout: bit field j holds columns [j*nw, (j+1)*nw)
+            idx = np.concatenate(
+                [(w >> (bits * j)) & m for j in range(epw)], axis=-1)
+            idx = idx[:, :, :D].astype(np.int64)
             vals = np.take_along_axis(np.asarray(lv, np.float32), idx,
                                       axis=-1)
             return vals.reshape(B, C, KV, HD)

@@ -51,6 +51,33 @@ class TestMakeHostMeshValidation:
             make_host_mesh(pods=n + 1)
 
 
+class TestMakeHostMeshDevices:
+    """``devices=`` takes a prefix of the visible devices (a one-chip run
+    on a four-chip host), and every axis is Auto so the model's sharding
+    hints apply inside the train step."""
+
+    def test_prefix(self):
+        mesh = make_host_mesh(devices=1)
+        assert mesh.devices.size == 1
+        assert mesh.devices.flat[0] == jax.devices()[0]
+
+    def test_more_than_visible_rejected(self):
+        n = len(jax.devices())
+        with pytest.raises(ValueError, match="visible"):
+            make_host_mesh(devices=n + 1)
+        with pytest.raises(ValueError, match="devices must be a positive"):
+            make_host_mesh(devices=0)
+
+    def test_factors_checked_against_the_prefix(self):
+        with pytest.raises(ValueError, match="must equal the device count"):
+            make_host_mesh(data=2, devices=1)
+
+    def test_axes_are_auto(self):
+        for mesh in (make_host_mesh(), make_host_mesh(pods=1, devices=1)):
+            assert all(t == jax.sharding.AxisType.Auto
+                       for t in mesh.axis_types)
+
+
 class TestDpAxisNames:
     """The deduped dp-axis selection (utils/sharding.dp_axis_names): the
     single source the train step, dryrun, and the hierarchy split share."""

@@ -67,6 +67,57 @@ class TestSingleMachine:
         assert losses[-1] < losses[0] - 0.3, (name, losses[::10])
 
 
+def test_host_mesh_quantized_step_multi_device():
+    """The launchers' mesh (``make_host_mesh``, data=4 x model=1): orq-9
+    with error feedback trains in replicated and fsdp mode, replicas stay
+    bit-identical, and step 0's loss equals fp's (same params, same
+    batch). jax 0.9's ``make_mesh`` defaults to Explicit axes, under
+    which the model's ``model``-axis sharding hints are refused."""
+    out = run_devices("""
+import hashlib
+import jax, numpy as np
+from repro.configs.base import get_smoke_config
+from repro.core import QuantPolicy
+from repro.data import SyntheticLM
+from repro.launch.mesh import make_host_mesh
+from repro.models import LM
+from repro.optim.schedule import constant_lr
+from repro.train import TrainConfig, make_train_step
+from repro.train.step import init_state
+
+cfg = get_smoke_config("lm-100m")
+model = LM(cfg)
+mesh = make_host_mesh(data=4, model=1)
+data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=16, batch_size=8,
+                   seed=1)
+for mode in ("replicated", "fsdp"):
+    first = {}
+    for quant in ("orq-9", "fp"):
+        tcfg = TrainConfig(policy=QuantPolicy.parse(quant, bucket_size=512),
+                           mode=mode, error_feedback=quant != "fp")
+        state = init_state(model, mesh, tcfg, jax.random.key(0))
+        step, _ = make_train_step(model, mesh, tcfg, constant_lr(0.05))
+        losses = []
+        for i in range(2):
+            state, m = step(state, data.batch(i), jax.random.key(1))
+            losses.append(float(m["loss"]))
+        assert np.isfinite(losses).all(), (mode, quant, losses)
+        first[quant] = losses[0]
+        if mode == "replicated":
+            digests = set()
+            for d in range(4):
+                h = hashlib.sha256()
+                for leaf in jax.tree_util.tree_leaves(state.params):
+                    h.update(np.asarray(leaf.addressable_shards[d].data)
+                             .tobytes())
+                digests.add(h.hexdigest())
+            assert len(digests) == 1, (mode, quant)
+    assert first["orq-9"] == first["fp"], (mode, first)
+print("OK")
+""", n=4)
+    assert "OK" in out
+
+
 def test_fsdp_mode_multi_device():
     """fsdp mode on a 4x2 (data, model) mesh: runs, loss decreases, and the
     fp-quantizer fsdp step matches the replicated fp step numerically."""
