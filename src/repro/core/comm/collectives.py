@@ -20,6 +20,12 @@ The paper's parameter-server exchange maps onto two collective phases inside
 
 The wire format (fit + round + uint32 bit-pack) lives in
 ``repro.core.comm.wire``; this module owns the collective choreography.
+
+Named scopes (``jax.named_scope``, op metadata only): phase 1 runs under
+``reduce``, phase 2 under ``requantize``, the error-feedback residual's
+local quantize->dequantize under ``ef``, and every collective of the
+exchange under ``collective`` (the wrappers below, which the other
+exchange modules use too).
 """
 from __future__ import annotations
 
@@ -62,6 +68,31 @@ def axis_size(axis_names) -> int:
     for a in _names(axis_names):
         n *= lax.axis_size(a)
     return n
+
+
+# ---------------------------------------------------------------------------
+# the exchange's collectives, each under the ``collective`` scope
+# ---------------------------------------------------------------------------
+
+@jax.named_scope("collective")
+def all_to_all(x, names):
+    """Row ``i`` of ``x`` to worker ``i``; row ``i`` back from each."""
+    return lax.all_to_all(x, names, split_axis=0, concat_axis=0)
+
+
+@jax.named_scope("collective")
+def all_gather(x, names, **kw):
+    return lax.all_gather(x, names, **kw)
+
+
+@jax.named_scope("collective")
+def psum_scatter(x, names, **kw):
+    return lax.psum_scatter(x, names, **kw)
+
+
+@jax.named_scope("collective")
+def pmean(x, names):
+    return lax.pmean(x, names)
 
 
 # ---------------------------------------------------------------------------
@@ -114,6 +145,7 @@ def _unbucket_rows(rows: jnp.ndarray, L: int, chunk: int) -> jnp.ndarray:
         [flat[i * width:i * width + chunk] for i in range(L)])
 
 
+@jax.named_scope("reduce")
 def _rs_mean_parts(parts, valid, qz: Quantizer, key, names, use_kernels,
                    pipeline_chunks: int = 1):
     """parts (L, chunk) local contributions, one row per destination worker;
@@ -143,8 +175,8 @@ def _rs_mean_parts(parts, valid, qz: Quantizer, key, names, use_kernels,
         words = words.reshape(L, nbc, -1)
         levels = levels.reshape(L, nbc, -1)
         # the wire: uint32 payload + f32 level tables
-        words = lax.all_to_all(words, names, split_axis=0, concat_axis=0)
-        levels = lax.all_to_all(levels, names, split_axis=0, concat_axis=0)
+        words = all_to_all(words, names)
+        levels = all_to_all(levels, names)
         mean_bkt = wire.decode_mean(qz, words, levels, d_eff,
                                     use_kernels=use_kernels)
         return mean_bkt.reshape(-1)[:chunk]
@@ -165,8 +197,8 @@ def _rs_mean_parts(parts, valid, qz: Quantizer, key, names, use_kernels,
             else rbits[:, a:b].reshape(L * sz, d_eff))
         sw = sw.reshape(L, sz, -1)
         sl = sl.reshape(L, sz, -1)
-        sw = lax.all_to_all(sw, names, split_axis=0, concat_axis=0)
-        sl = lax.all_to_all(sl, names, split_axis=0, concat_axis=0)
+        sw = all_to_all(sw, names)
+        sl = all_to_all(sl, names)
         means.append(wire.decode_mean(qz, sw, sl, d_eff,
                                       use_kernels=use_kernels))
     mean_bkt = jnp.concatenate(means, axis=0)             # (nbc, d_eff)
@@ -211,9 +243,9 @@ def quantized_reduce_scatter_mean(
     chunk = -(-n // L)
     padded = jnp.pad(flat, (0, L * chunk - n))
     if qz.is_identity:
-        return lax.psum_scatter(
-            padded.reshape(L, chunk), names, scatter_dimension=0,
-            tiled=False) / L
+        with jax.named_scope("reduce"):
+            return psum_scatter(padded.reshape(L, chunk), names,
+                                scatter_dimension=0, tiled=False) / L
     valid = _valid_parts(valid, n, L, chunk)
     if worker_id is None:
         worker_id = lax.axis_index(names)
@@ -226,6 +258,7 @@ def quantized_reduce_scatter_mean(
 # phase 1 + 2: quantized all-reduce (mean), replicated-parameter mode
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("ef")
 def local_qdq_comm_layout(
     flat: jnp.ndarray,
     qz: Quantizer,
@@ -274,19 +307,33 @@ def quantized_all_reduce_mean(
     levels on valid data only). ``pipeline_chunks`` chunks BOTH phases —
     phase 2's re-quantize + all_gather pipelines over the same bucket-row
     spans as phase 1 — and stays bit-identical to the single-shot path."""
-    n = flat.shape[0]
     names = _names(axis_names)
-    L = axis_size(names)
     if qz.is_identity:
-        return lax.pmean(flat, names)
+        return pmean(flat, names)
 
-    chunk = -(-n // L)
     mean_chunk = quantized_reduce_scatter_mean(
         flat, qz, key, names, worker_id=worker_id, use_kernels=use_kernels,
         valid=valid, pipeline_chunks=pipeline_chunks)
 
+    return _requantize_all_gather(
+        mean_chunk, flat, qz, key, names, worker_id=worker_id,
+        server_requant=server_requant, use_kernels=use_kernels, valid=valid,
+        pipeline_chunks=pipeline_chunks)
+
+
+@jax.named_scope("requantize")
+def _requantize_all_gather(mean_chunk, flat, qz: Quantizer, key, names, *,
+                           worker_id, server_requant: bool,
+                           use_kernels: bool, valid,
+                           pipeline_chunks: int) -> jnp.ndarray:
+    """Phase 2 of :func:`quantized_all_reduce_mean`: this worker's averaged
+    ``mean_chunk`` re-quantized with fresh levels and all-gathered (f32
+    when ``server_requant`` is off); returns the (n,) mean of ``flat``."""
+    n = flat.shape[0]
+    L = axis_size(names)
+    chunk = -(-n // L)
     if not server_requant:
-        full = lax.all_gather(mean_chunk, names, axis=0, tiled=False)
+        full = all_gather(mean_chunk, names, axis=0, tiled=False)
         return full.reshape(-1)[:n].astype(flat.dtype)
 
     # phase 2: re-quantize the averaged chunk; broadcast payload + levels.
@@ -307,8 +354,8 @@ def quantized_all_reduce_mean(
     if len(spans) == 1:
         words, levels = wire.encode(qz, bkt, mask, key2,
                                     use_kernels=use_kernels)
-        words = lax.all_gather(words, names, axis=0, tiled=False)
-        levels_all = lax.all_gather(levels, names, axis=0, tiled=False)
+        words = all_gather(words, names, axis=0, tiled=False)
+        levels_all = all_gather(levels, names, axis=0, tiled=False)
         vals = wire.decode_each(qz, words, levels_all, d_eff,
                                 use_kernels=use_kernels)  # (L, nbc, d_eff)
     else:
@@ -319,8 +366,8 @@ def quantized_all_reduce_mean(
             sw, sl = wire.encode(qz, bkt[a:b], mask[a:b], key2,
                                  use_kernels=use_kernels,
                                  rbits=None if rbits is None else rbits[a:b])
-            sw = lax.all_gather(sw, names, axis=0, tiled=False)
-            sl = lax.all_gather(sl, names, axis=0, tiled=False)
+            sw = all_gather(sw, names, axis=0, tiled=False)
+            sl = all_gather(sl, names, axis=0, tiled=False)
             parts.append(wire.decode_each(qz, sw, sl, d_eff,
                                           use_kernels=use_kernels))
         vals = jnp.concatenate(parts, axis=1)             # (L, nbc, d_eff)
@@ -329,4 +376,4 @@ def quantized_all_reduce_mean(
 
 def psum_mean_tree(tree, axis_names):
     """FP baseline: plain pmean over the dp axes for a whole pytree."""
-    return jax.tree_util.tree_map(lambda x: lax.pmean(x, axis_names), tree)
+    return jax.tree_util.tree_map(lambda x: pmean(x, axis_names), tree)

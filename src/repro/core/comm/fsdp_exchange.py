@@ -59,8 +59,10 @@ from jax import lax
 
 from repro.core.api import QuantConfig
 from repro.core.comm import wire
-from repro.core.comm.collectives import (_names, _rs_mean_parts, axis_size,
+from repro.core.comm.collectives import (_names, _rs_mean_parts,
+                                         all_gather, axis_size,
                                          local_qdq_comm_layout,
+                                         psum_scatter,
                                          quantized_reduce_scatter_mean)
 from repro.core.comm.exchange import GradientExchange, link_stats
 from repro.core.policy import QuantPolicy
@@ -86,7 +88,7 @@ def reduce_scatter_mean_block(g, qz: Quantizer, key, axis_names, *, dim: int,
     chunk = (lead // L) * int(np.prod(rest)) if rest else lead // L
     parts = gm.reshape(L, chunk)
     if qz.is_identity:
-        mean_chunk = lax.psum_scatter(
+        mean_chunk = psum_scatter(
             parts, names, scatter_dimension=0, tiled=False) / L
     else:
         valid = jnp.ones((L, chunk), dtype=bool)
@@ -238,7 +240,7 @@ class FsdpLayout:
                 for i in g.leaf_ids])
             # worker-major (L * width,); every leaf is cut from it with
             # 1-D slices (see collectives._bucket_rows for why not 2-D)
-            rows = lax.all_gather(row, names, axis=0, tiled=True)
+            rows = all_gather(row, names, axis=0, tiled=True)
             width = row.shape[0]
             for i in g.leaf_ids:
                 s = self.slots[i]
@@ -438,7 +440,7 @@ class FsdpExchange:
         L = self.layout.n_shards
         chunk = buf.shape[0] // L
         parts = buf.reshape(self.n_inter, self.n_intra, chunk)
-        intra_mean = lax.psum_scatter(
+        intra_mean = psum_scatter(
             parts, _names(self.intra_axes), scatter_dimension=1,
             tiled=False) / self.n_intra
         return intra_mean.reshape(-1)
@@ -693,10 +695,12 @@ def make_fused_tree_gather(ex: FsdpExchange, *,
          train step persists ``new_ef`` in ``TrainState.ef``.
 
     Pass ``ef_bufs=None`` to disable error feedback (no residual compute,
-    no residual cotangent)."""
+    no residual cotangent). Both directions run under the ``exchange``
+    named scope."""
     names = _names(ex.axis_names)
 
     @jax.custom_vjp
+    @jax.named_scope("exchange")
     def gather(shard_params, ef_bufs, key):
         del ef_bufs, key
         return ex.layout.gather_full(shard_params, names,
@@ -708,6 +712,7 @@ def make_fused_tree_gather(ex: FsdpExchange, *,
         wid = lax.axis_index(names)
         return gather(shard_params, ef_bufs, key), (key, wid, ef_bufs)
 
+    @jax.named_scope("exchange")
     def bwd(res, g_full):
         key, wid, ef_bufs = res
         bufs = ex.layout.flatten_groups(g_full)

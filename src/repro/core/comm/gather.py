@@ -16,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.core.comm.collectives import _names, quantized_all_reduce_mean
+from repro.core.comm.collectives import (_names, all_gather, pmean,
+                                         quantized_all_reduce_mean)
 from repro.core.comm.fsdp_exchange import reduce_scatter_mean_block
 from repro.core.quantizers import Quantizer
 from repro.utils.compat import shard_map
@@ -50,10 +51,11 @@ def make_fsdp_gather(
     names = _names(axis_names)
 
     @jax.custom_vjp
+    @jax.named_scope("exchange")
     def gather(w, key):
         del key
-        return lax.all_gather(w.astype(compute_dtype), names, axis=dim,
-                              tiled=True)
+        return all_gather(w.astype(compute_dtype), names, axis=dim,
+                          tiled=True)
 
     def fwd(w, key):
         # capture the worker id in the PRIMAL context: axis_index cannot
@@ -68,6 +70,7 @@ def make_fsdp_gather(
                                          use_kernels=use_kernels,
                                          param_dtype=param_dtype)
 
+    @jax.named_scope("exchange")
     def bwd(res, g):
         key, wid = res
         key_w = jax.random.fold_in(key, wid)
@@ -117,11 +120,12 @@ def make_replicated_gather(
         wid = lax.axis_index(names)   # primal context (see make_fsdp_gather)
         return gather(w, key), (key, wid)
 
+    @jax.named_scope("exchange")
     def bwd(res, g):
         key, wid = res
         flat = g.astype(jnp.float32).reshape(-1)
         if qz.is_identity:
-            mean = lax.pmean(flat, names)
+            mean = pmean(flat, names)
         else:
             mean = quantized_all_reduce_mean(
                 flat, qz, key, names, worker_id=wid,

@@ -36,7 +36,8 @@ from typing import Tuple
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.comm.collectives import _names, axis_size
+from repro.core.comm.collectives import (_names, all_gather, axis_size,
+                                         psum_scatter)
 
 # dp axes that cross the slow inter-pod (DCN) boundary; everything else in
 # the dp tuple is a fast intra-pod (ICI) axis. Matches the mesh layer's
@@ -115,15 +116,15 @@ def intra_reduce_scatter_mean(flat: jnp.ndarray, intra_names) -> jnp.ndarray:
     n = flat.shape[0]
     chunk = intra_chunk_len(n, L)
     padded = jnp.pad(flat.astype(jnp.float32), (0, L * chunk - n))
-    return lax.psum_scatter(padded.reshape(L, chunk), names,
-                            scatter_dimension=0, tiled=False) / L
+    return psum_scatter(padded.reshape(L, chunk), names,
+                        scatter_dimension=0, tiled=False) / L
 
 
 def intra_all_gather(shard: jnp.ndarray, intra_names, n: int) -> jnp.ndarray:
     """(chunk,) per-worker shard -> the reassembled (n,) buffer (one
     all_gather on the fast ICI link; inverse of the scatter above)."""
     names = _names(intra_names)
-    full = lax.all_gather(shard, names, axis=0, tiled=False)
+    full = all_gather(shard, names, axis=0, tiled=False)
     return full.reshape(-1)[:n]
 
 

@@ -27,6 +27,11 @@ multi-pass alike) accumulate ``val/L`` per worker while the oracle sums
 then scales, so kernel-vs-oracle equality there is exact only when the
 worker count is a power of two (scaling by 2^-k never rounds) and
 float-close otherwise; every other op is exact everywhere.
+
+Named scopes (``jax.named_scope``, op metadata only): the level fit runs
+under ``fit`` (``Quantizer.fit``; BinGrad-b's fused fit-and-encode kernel
+too), the rounding stream under ``rbits``, the rest of an encode or local
+quantize->dequantize under ``encode``, and every decode under ``decode``.
 """
 from __future__ import annotations
 
@@ -87,6 +92,7 @@ def _fused_mode(qz: Quantizer) -> str:
     return ""
 
 
+@jax.named_scope("rbits")
 def encode_rbits(qz: Quantizer, key, shape):
     """The threefry uint32 stream :func:`encode` would draw for a ``shape``
     bucket layout (None for the deterministic schemes). The pipelined
@@ -120,18 +126,21 @@ def encode(qz: Quantizer, bkt, mask, key, *, use_kernels: bool = True,
     mode = _fused_mode(qz)
     if mode == "bin":
         # b₀ search + conditional-mean levels + threshold + pack, one sweep
-        return ops.encode_bingrad(bkt, mask, clip_c=qz.clip_c,
-                                  lloyd_iters=qz.lloyd_iters,
-                                  use_kernels=use_kernels)
+        with jax.named_scope("fit"):
+            return ops.encode_bingrad(bkt, mask, clip_c=qz.clip_c,
+                                      lloyd_iters=qz.lloyd_iters,
+                                      use_kernels=use_kernels)
     if not mode:
         return encode_multipass(qz, bkt, mask, key, use_kernels=use_kernels)
     levels = qz.fit(bkt, mask)                            # runtime levels
     if mode == "rr" and rbits is None:
         rbits = encode_rbits(qz, key, bkt.shape)
-    words = ops.encode_fused(bkt, levels, rbits if mode == "rr" else None,
-                             mask, bits=qz.wire_bits_per_element,
-                             clip_c=qz.clip_c, mode=mode,
-                             use_kernels=use_kernels)
+    with jax.named_scope("encode"):
+        words = ops.encode_fused(bkt, levels,
+                                 rbits if mode == "rr" else None, mask,
+                                 bits=qz.wire_bits_per_element,
+                                 clip_c=qz.clip_c, mode=mode,
+                                 use_kernels=use_kernels)
     return words, levels
 
 
@@ -142,9 +151,11 @@ def encode_multipass(qz: Quantizer, bkt, mask, key, *,
     select -> pack kernel, each materializing (nb, d) intermediates).
     Kept as the parity/regression baseline for the fused path."""
     levels = qz.fit(bkt, mask)                            # runtime levels
-    idx = jnp.where(mask, assign(qz, bkt, levels, key, use_kernels,
-                                 mask=mask), 0)
-    words = ops.pack(idx, qz.wire_bits_per_element, use_kernels=use_kernels)
+    with jax.named_scope("encode"):
+        idx = jnp.where(mask, assign(qz, bkt, levels, key, use_kernels,
+                                     mask=mask), 0)
+        words = ops.pack(idx, qz.wire_bits_per_element,
+                         use_kernels=use_kernels)
     return words, levels
 
 
@@ -156,19 +167,20 @@ def qdq(qz: Quantizer, bkt, mask, key, *,
     error-feedback residual hot path — one ``pallas_call``, no idx or
     pack/unpack round-trip (masked-out slots decode to level 0 exactly
     like the multi-pass path)."""
-    from repro.core import rounding as R
-
     levels = qz.fit(bkt, mask)
     mode = _fused_mode(qz)
     if not mode:
-        idx = jnp.where(mask, assign(qz, bkt, levels, key, use_kernels,
-                                     mask=mask), 0)
-        return Quantizer.decode(idx, levels)
-    rbits = R.random_bits(key, bkt.shape) if mode == "rr" else None
-    return ops.qdq_fused(bkt, levels, rbits, mask, clip_c=qz.clip_c,
-                         mode=mode, use_kernels=use_kernels)
+        with jax.named_scope("encode"):
+            idx = jnp.where(mask, assign(qz, bkt, levels, key, use_kernels,
+                                         mask=mask), 0)
+            return Quantizer.decode(idx, levels)
+    rbits = encode_rbits(qz, key, bkt.shape)
+    with jax.named_scope("encode"):
+        return ops.qdq_fused(bkt, levels, rbits, mask, clip_c=qz.clip_c,
+                             mode=mode, use_kernels=use_kernels)
 
 
+@jax.named_scope("decode")
 def decode(qz: Quantizer, words, levels, d_eff: int, *, average: bool = True,
            use_kernels: bool = True) -> jnp.ndarray:
     """Decode L stacked wire units in ONE ``pallas_call``: unpack +
@@ -200,6 +212,7 @@ def decode_each(qz: Quantizer, words, levels, d_eff: int, *,
                   use_kernels=use_kernels)
 
 
+@jax.named_scope("decode")
 def decode_mean_multipass(qz: Quantizer, words, levels, d_eff: int, *,
                           use_kernels: bool = True) -> jnp.ndarray:
     """The PR-1..4 multi-pass mean decode (vmapped unpack kernel writing
@@ -211,6 +224,7 @@ def decode_mean_multipass(qz: Quantizer, words, levels, d_eff: int, *,
     return ops.dequant_avg(idx_all, levels, use_kernels=use_kernels)
 
 
+@jax.named_scope("decode")
 def decode_each_multipass(qz: Quantizer, words, levels, d_eff: int, *,
                           use_kernels: bool = True) -> jnp.ndarray:
     """The PR-1..4 multi-pass per-worker decode. Parity baseline."""

@@ -77,6 +77,7 @@ class Quantizer:
         return self.method == "fp"
 
     # ------------------------------------------------------------------
+    @jax.named_scope("fit")
     def fit(self, bkt: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
         if self.clip_c is not None and self.method not in ("fp",):
             bkt = clipping.sigma_clip(bkt, mask, self.clip_c)

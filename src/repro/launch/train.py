@@ -16,6 +16,15 @@ mesh is exercised via launch/dryrun.py. Examples:
     PYTHONPATH=src python -m repro.launch.train --arch lm-100m --smoke \
         --bit-schedule "norm|bias=fp,default=orq@5..2" \
         --bit-budget 2e5 --resolve-every 25 --mode replicated
+
+    # profile steps 2-4 (after the compile) into a TensorBoard/xprof log
+    PYTHONPATH=src python -m repro.launch.train --arch lm-100m --smoke \
+        --steps 8 --quant orq-9 --trace-dir /tmp/profile
+
+Each step is a ``jax.profiler.StepTraceAnnotation`` ("train") holding the
+host spans ``train:batch``, ``train:step``, ``train:metrics`` and
+``train:checkpoint``; the step's device ops carry the program's named
+scopes (README, "Splitting a step").
 """
 from __future__ import annotations
 
@@ -149,6 +158,10 @@ def main(argv=None):
                          "its step counter (strict load: the tree must "
                          "match the configured run exactly)")
     ap.add_argument("--metrics-out", default=None)
+    ap.add_argument("--trace-dir", default=None, metavar="DIR",
+                    help="profile steps start+2 .. start+4 (after the "
+                         "compile) with jax.profiler and write the trace "
+                         "under DIR")
     args = ap.parse_args(argv)
     if args.checkpoint_at is not None and not args.state_checkpoint:
         ap.error("--checkpoint-at needs --state-checkpoint")
@@ -248,39 +261,65 @@ def main(argv=None):
         print(f"resumed {args.resume} at step {start}")
     history = []
     print(f"device: {device_label()}, mesh {dict(mesh.shape)}")
-    t0 = time.time()
+    span = jax.profiler.TraceAnnotation
+    # steps start+2 .. start+4: after the compile, one traced window
+    traced = (range(start + 2, min(start + 5, args.steps))
+              if args.trace_dir else range(0))
+    t0 = time.perf_counter()
     for i in range(start, args.steps):
-        batch = data.batch(i)
-        state, metrics = step_fn(state, batch, jax.random.key(args.seed))
-        if args.state_checkpoint and args.checkpoint_at == i + 1:
-            save_checkpoint(args.state_checkpoint, state,
-                            step=int(state.step))
-            print(f"state checkpoint -> {args.state_checkpoint} "
-                  f"at step {i + 1}")
-        if i % args.log_every == 0 or i == args.steps - 1:
-            loss = float(metrics["loss"])
-            row = {"step": i, "loss": loss,
-                   "nll": float(metrics["nll"]),
-                   "lr": float(metrics["lr"])}
-            bits = ""
-            if controller is not None:
-                row["bits"] = list(step_fn.last_assignment)
-                bits = " bits " + ",".join(
-                    "fp" if b is None else str(b) for b in row["bits"])
-            history.append(row)
-            print(f"step {i:5d} loss {loss:.4f}{bits} "
-                  f"({(time.time()-t0)/(i-start+1):.2f}s/step)")
+        if traced and i == traced.start:
+            jax.block_until_ready(state)
+            jax.profiler.start_trace(args.trace_dir)
+        with jax.profiler.StepTraceAnnotation("train", step_num=i):
+            with span("train:batch"):
+                batch = data.batch(i)
+            with span("train:step"):
+                state, metrics = step_fn(state, batch,
+                                         jax.random.key(args.seed))
+            if i == start:
+                # the first step compiles; the rate counts from its end
+                jax.block_until_ready(state)
+                t1 = time.perf_counter()
+            if args.state_checkpoint and args.checkpoint_at == i + 1:
+                with span("train:checkpoint"):
+                    save_checkpoint(args.state_checkpoint, state,
+                                    step=int(state.step))
+                print(f"state checkpoint -> {args.state_checkpoint} "
+                      f"at step {i + 1}")
+            if i % args.log_every == 0 or i == args.steps - 1:
+                with span("train:metrics"):
+                    loss = float(metrics["loss"])
+                    row = {"step": i, "loss": loss,
+                           "nll": float(metrics["nll"]),
+                           "lr": float(metrics["lr"])}
+                bits = ""
+                if controller is not None:
+                    row["bits"] = list(step_fn.last_assignment)
+                    bits = " bits " + ",".join(
+                        "fp" if b is None else str(b) for b in row["bits"])
+                history.append(row)
+                rate = (f"{(time.perf_counter() - t1) / (i - start):.2f}"
+                        f"s/step" if i > start else
+                        f"first step {t1 - t0:.2f}s, compile included")
+                print(f"step {i:5d} loss {loss:.4f}{bits} ({rate})")
+        if traced and i == traced.stop - 1:
+            jax.block_until_ready(state)
+            jax.profiler.stop_trace()
+            print(f"trace of steps {traced.start}-{i} -> {args.trace_dir}")
     # bit-level fingerprint of the final parameters: two runs of an
     # exchange schedule that is supposed to be bit-identical (e.g.
     # --pipeline-chunks K vs 1) must print the same digest
     digest = _params_digest(state.params)
     print("params sha256", digest)
     if args.checkpoint:
-        save_checkpoint(args.checkpoint, state.params,
-                        step=int(state.step))
+        with span("train:checkpoint"):
+            save_checkpoint(args.checkpoint, state.params,
+                            step=int(state.step))
         print("checkpoint ->", args.checkpoint)
     if args.state_checkpoint and args.checkpoint_at is None:
-        save_checkpoint(args.state_checkpoint, state, step=int(state.step))
+        with span("train:checkpoint"):
+            save_checkpoint(args.state_checkpoint, state,
+                            step=int(state.step))
         print("state checkpoint ->", args.state_checkpoint)
     if args.metrics_out:
         out = {"history": history, "params_sha256": digest}

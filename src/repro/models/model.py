@@ -272,10 +272,13 @@ class LM:
         lg = (x @ head.astype(x.dtype)).astype(jnp.float32)
         return softcap(lg, self.cfg.final_softcap), aux
 
+    @jax.named_scope("model")
     def loss(self, params, batch, gather: GatherFn = _identity_gather,
              *, loss_chunk: int = 512):
         """batch: {tokens (B,S) [, enc_embeds (B,F,D)]}. Next-token xent,
-        computed in sequence chunks so (B,S,V) never materializes."""
+        computed in sequence chunks so (B,S,V) never materializes. Runs
+        under the ``model`` named scope: differentiated, its forward ops
+        carry ``jvp(model)`` and its backward ``transpose(jvp(model))``."""
         cfg = self.cfg
         tokens = batch["tokens"]
         B, S = tokens.shape

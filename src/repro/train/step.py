@@ -715,6 +715,14 @@ def make_train_step(model: LM, mesh, tcfg: TrainConfig, lr_fn=None,
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
 
+        with jax.named_scope("exchange"):
+            grads, new_ef, stats = exchange_grads(state, grads, step_key)
+        return _finish(state, grads, new_ef, loss, metrics, stats)
+
+    def exchange_grads(state: TrainState, grads, step_key):
+        """The exchange of the replicated and single-device paths: EF
+        compensation, the quantized all-reduce (or local qdq) and the new
+        residuals -> ``(grads, new_ef, stats)``."""
         new_ef = state.ef
         stats = None
         use_ef = (tcfg.error_feedback and state.ef is not None
@@ -831,14 +839,15 @@ def make_train_step(model: LM, mesh, tcfg: TrainConfig, lr_fn=None,
                         grads, quantized)
                 grads = quantized
 
-        return _finish(state, grads, new_ef, loss, metrics, stats)
+        return grads, new_ef, stats
 
     def _finish(state: TrainState, grads, new_ef, loss, metrics,
                 stats=None):
         lr = lr_fn(state.step)
-        updates, new_opt = optimizer.update(grads, state.opt, state.params,
-                                            lr)
-        new_params = opt_lib.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt,
+                                                state.params, lr)
+            new_params = opt_lib.apply_updates(state.params, updates)
         if stats is not None:
             # (n_groups, 3) controller feed; pmean'd with the rest below
             metrics = dict(metrics, exchange_stats=stats)
@@ -1002,12 +1011,14 @@ def _make_async_train_step(model: LM, mesh, tcfg: TrainConfig, lr_fn,
         if intra_axes:
             # ONE multi-operand psum over the fast ICI axes; inner steps
             # never touch the DCN tier (the point of the temporal split)
-            grads = jax.lax.pmean(grads, intra_axes)
+            with jax.named_scope("exchange"):
+                grads = comm.collectives.pmean(grads, intra_axes)
         lr = lr_fn(state.step)
-        updates, new_opt = optimizer.update(grads, unstack(state.opt),
-                                            params, lr)
-        return (opt_lib.apply_updates(params, updates), new_opt, loss,
-                metrics, lr)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, unstack(state.opt),
+                                                params, lr)
+            new_params = opt_lib.apply_updates(params, updates)
+        return new_params, new_opt, loss, metrics, lr
 
     def _pack(state, new_params, new_opt, new_ef, outer, loss, metrics,
               lr, stats=None):
@@ -1042,49 +1053,55 @@ def _make_async_train_step(model: LM, mesh, tcfg: TrainConfig, lr_fn,
             state.outer.anchor, new_params)
         step_key = jax.random.fold_in(key, state.step)
         k = jax.random.fold_in(step_key, _FUSED_SALT)
-        bufs = pex.layout.flatten_groups(delta)
-        new_ef = state.ef
-        stats = None
-        if two_level:
-            # the literal two_level wire path, fed the delta: fp intra
-            # scatter -> EF add on the shard -> quantized Algorithm 2
-            # over the pod axes only -> fp intra gather
-            shards, valids = pex.intra_scatter_parts(bufs)
-            if use_ef:
-                shards = tuple(s if e is None else s + e
-                               for s, e in zip(shards, state.ef))
-                local = pex.local_qdq_shard_parts(shards, k, valids)
-                new_ef = tuple(None if e is None else s - l
-                               for e, s, l in zip(state.ef, shards,
-                                                  local))
-            if collect_stats:
-                stats = pex.group_stats(shards, new_ef if use_ef else None)
-            mean_shards = pex.exchange_shard_parts(shards, k, valids)
-            delta_mean = pex.layout.unflatten_groups(
-                pex.intra_gather_parts(mean_shards), restore_dtype=False)
-        else:
-            # degenerate intra half (pods-only dp mesh): the outer
-            # exchange runs flat over all dp axes, EF on the full buffers
-            if use_ef:
-                bufs = tuple(b if e is None else b + e
-                             for b, e in zip(bufs, state.ef))
-                local = pex.local_qdq_parts(bufs, k)
-                new_ef = tuple(None if e is None else b - l
-                               for e, b, l in zip(state.ef, bufs, local))
-            if collect_stats:
-                stats = pex.group_stats(bufs, new_ef if use_ef else None)
-            delta_mean = pex.layout.unflatten_groups(
-                pex.exchange_parts(bufs, k), restore_dtype=False)
+        with jax.named_scope("exchange"):
+            bufs = pex.layout.flatten_groups(delta)
+            new_ef = state.ef
+            stats = None
+            if two_level:
+                # the literal two_level wire path, fed the delta: fp intra
+                # scatter -> EF add on the shard -> quantized Algorithm 2
+                # over the pod axes only -> fp intra gather
+                shards, valids = pex.intra_scatter_parts(bufs)
+                if use_ef:
+                    shards = tuple(s if e is None else s + e
+                                   for s, e in zip(shards, state.ef))
+                    local = pex.local_qdq_shard_parts(shards, k, valids)
+                    new_ef = tuple(None if e is None else s - l
+                                   for e, s, l in zip(state.ef, shards,
+                                                      local))
+                if collect_stats:
+                    stats = pex.group_stats(shards,
+                                            new_ef if use_ef else None)
+                mean_shards = pex.exchange_shard_parts(shards, k, valids)
+                delta_mean = pex.layout.unflatten_groups(
+                    pex.intra_gather_parts(mean_shards), restore_dtype=False)
+            else:
+                # degenerate intra half (pods-only dp mesh): the outer
+                # exchange runs flat over all dp axes, EF on the full
+                # buffers
+                if use_ef:
+                    bufs = tuple(b if e is None else b + e
+                                 for b, e in zip(bufs, state.ef))
+                    local = pex.local_qdq_parts(bufs, k)
+                    new_ef = tuple(None if e is None else b - l
+                                   for e, b, l in zip(state.ef, bufs,
+                                                      local))
+                if collect_stats:
+                    stats = pex.group_stats(bufs,
+                                            new_ef if use_ef else None)
+                delta_mean = pex.layout.unflatten_groups(
+                    pex.exchange_parts(bufs, k), restore_dtype=False)
         # outer optimizer on the exchanged mean pseudo-gradient; its
         # output is globally identical, so anchor/mom stay replicated
-        mom = jax.tree_util.tree_map(
-            lambda m, d: outer_mu * m + d, state.outer.mom, delta_mean)
-        upd = (jax.tree_util.tree_map(
-                   lambda d, m: d + outer_mu * m, delta_mean, mom)
-               if nesterov else mom)
-        outer_params = jax.tree_util.tree_map(
-            lambda a, u: (a - outer_lr * u).astype(a.dtype),
-            state.outer.anchor, upd)
+        with jax.named_scope("optimizer"):
+            mom = jax.tree_util.tree_map(
+                lambda m, d: outer_mu * m + d, state.outer.mom, delta_mean)
+            upd = (jax.tree_util.tree_map(
+                       lambda d, m: d + outer_mu * m, delta_mean, mom)
+                   if nesterov else mom)
+            outer_params = jax.tree_util.tree_map(
+                lambda a, u: (a - outer_lr * u).astype(a.dtype),
+                state.outer.anchor, upd)
         outer = OuterState(anchor=outer_params, mom=mom)
         return _pack(state, outer_params, new_opt, new_ef, outer, loss,
                      metrics, lr, stats)

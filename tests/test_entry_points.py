@@ -45,8 +45,8 @@ class TestCompileCache:
 
 
 def test_chip_path_imports_no_host_device_forcing():
-    """dryrun, perf and the analysis CLI set JAX_PLATFORMS=cpu (and 512 or
-    8 fake devices) as they are imported; nothing on the chip path may
+    """dryrun and the analysis CLI set JAX_PLATFORMS=cpu (and 512 or 8
+    fake devices) as they are imported; nothing on the chip path may
     import them."""
     out = _run(["-c", textwrap.dedent("""
         import os, sys
@@ -57,7 +57,7 @@ def test_chip_path_imports_no_host_device_forcing():
         import repro.kernels.fused_kv, repro.serve.kv_cache
         import repro.launch.train, repro.launch.serve, repro.launch.mesh
         import repro.train.step, repro.optim.schedule
-        bad = [m for m in ("repro.launch.dryrun", "repro.launch.perf",
+        bad = [m for m in ("repro.launch.dryrun",
                            "repro.analysis.__main__") if m in sys.modules]
         assert not bad, bad
         assert "JAX_PLATFORMS" not in os.environ
@@ -101,3 +101,26 @@ def test_chip_smoke_rejects_kernel_overrides(var, value, why, monkeypatch):
     monkeypatch.setenv(var, value)
     with pytest.raises(SystemExit, match=why):
         chip_smoke.device_check(1)
+
+
+def test_train_trace_dir_writes_host_spans(tmp_path, capsys):
+    """``--trace-dir`` profiles the steps after the compile: the written
+    ``.xplane.pb`` holds the launcher's ``train:step`` host spans, one per
+    traced step, and its ``s/step`` lines leave the compiling step out."""
+    import glob
+
+    from repro.launch import train
+
+    train.main(["--arch", "lm-100m", "--smoke", "--steps", "4", "--batch",
+                "2", "--seq", "16", "--quant", "orq-9", "--log-every", "1",
+                "--trace-dir", str(tmp_path)])
+    out = capsys.readouterr().out
+    assert "first step" in out and "compile included" in out
+    assert f"trace of steps 2-3 -> {tmp_path}" in out
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    names = [e.name for p in pd.planes if p.name.startswith("/host:")
+             for line in p.lines for e in line.events]
+    assert names.count("train:step") == 2
+    assert names.count("train:batch") == 2
+    assert names.count("train:metrics") == 2
