@@ -51,6 +51,12 @@ TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS, MESH_STEPS = 8, 512, 5, 3
 #: wire indices may differ in at most MISMATCH_SHARE of elements
 FLOAT_NOISE = 1e-6
 MISMATCH_SHARE = 1e-4
+#: ORQ fits: the ``orq_fit`` kernel sums a bucket's interval in another
+#: order than XLA, so a midpoint solve on a half-way tie may take the
+#: neighbouring value; at most this share of buckets may hold another
+#: level table (1 in 4,096 against the sort-based solve on the CPU), and
+#: the other checks run on the rest
+TIE_SHARE = 1e-3
 
 
 def log(msg: str) -> None:
@@ -132,6 +138,16 @@ def _compare_floats(what, got, want):
           f"err {float(err.max()):.3e}")
 
 
+def _same_fit_rows(what, got, want) -> np.ndarray:
+    """Rows whose ORQ level tables agree; the others may be no more than
+    TIE_SHARE of the rows."""
+    same = (np.asarray(got) == np.asarray(want)).all(axis=-1)
+    share = float(np.mean(~same))
+    check(share <= TIE_SHARE, f"{what}: buckets with another level table "
+          f"{share:.3e} (bound {TIE_SHARE:g})")
+    return same
+
+
 def phase_kernels(seed: int) -> None:
     import jax
     import jax.numpy as jnp
@@ -157,14 +173,18 @@ def phase_kernels(seed: int) -> None:
         bits = qz.wire_bits_per_element
         (wk, lk), (wo, lo) = (wire.encode(qz, g, mask, k[2], use_kernels=u)
                               for u in (True, False))
+        rows = (_same_fit_rows(f"{name} fit", lk, lo) if qz.method == "orq"
+                else np.ones(GRAD_ROWS, bool))
         _compare_ints(f"{name} encode indices ({bits}-bit words)",
-                      encode.unpack(wk, bits, BUCKET),
-                      encode.unpack(wo, bits, BUCKET))
-        _compare_floats(f"{name} encode levels", lk, lo)
+                      np.asarray(encode.unpack(wk, bits, BUCKET))[rows],
+                      np.asarray(encode.unpack(wo, bits, BUCKET))[rows])
+        _compare_floats(f"{name} encode levels", np.asarray(lk)[rows],
+                        np.asarray(lo)[rows])
         if name not in ("orq-9", "bingrad-b"):
             continue
         _compare_floats(f"{name} qdq", *(
-            wire.qdq(qz, g, mask, k[2], use_kernels=u) for u in (True, False)))
+            np.asarray(wire.qdq(qz, g, mask, k[2], use_kernels=u))[rows]
+            for u in (True, False)))
         units = [wire.encode(qz, w, mask, k[3]) for w in workers]
         words = jnp.stack([u[0] for u in units])
         levels = jnp.stack([u[1] for u in units])
@@ -181,11 +201,14 @@ def phase_kernels(seed: int) -> None:
     kern, orac = (append_kv(qz, kr, vr, rbits, use_kernels=u)
                   for u in (True, False))           # (kw, klv, vw, vlv)
     for i, part in enumerate(("K", "V")):
-        _compare_ints(f"append_kv {part} indices",
-                      encode.unpack(kern[2 * i], spec.bits, d),
-                      encode.unpack(orac[2 * i], spec.bits, d))
-        _compare_floats(f"append_kv {part} levels", kern[2 * i + 1],
-                        orac[2 * i + 1])
+        rows = _same_fit_rows(f"append_kv {part} fit", kern[2 * i + 1],
+                              orac[2 * i + 1])
+        _compare_ints(f"append_kv {part} indices", *(
+            np.asarray(encode.unpack(x[2 * i], spec.bits, d))[rows]
+            for x in (kern, orac)))
+        _compare_floats(f"append_kv {part} levels",
+                        np.asarray(kern[2 * i + 1])[rows],
+                        np.asarray(orac[2 * i + 1])[rows])
     kw, klv, vw, vlv = (x.reshape(KV_BATCH, KV_CONTEXT, -1) for x in kern)
     q = jax.random.normal(k[0], (KV_BATCH, 1, cfg.num_heads, hd))
     fill = jax.random.randint(k[1], (KV_BATCH, 1, 1), 1, KV_CONTEXT + 1)

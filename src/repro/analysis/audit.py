@@ -92,11 +92,18 @@ ASYNC_MATRIX: List[Tuple[int, int]] = [
 # wire-op bundles (per registered scheme)
 # ---------------------------------------------------------------------------
 
+def fit_launches(qz) -> int:
+    """Kernel launches of one level fit: ORQ's ``orq_fit``; the other
+    schemes fit in jnp or inside their encode kernel."""
+    return 1 if qz.method == "orq" else 0
+
+
 def wire_bundles(schemes: Optional[Sequence[str]] = None, *, nb: int = 5,
                  d: int = 37, workers: int = 3) -> List[TraceBundle]:
     """One bundle per (scheme, op) on a ragged (nb, d) buffer: exactly one
-    pallas_call per fused op (zero under the reference oracle), and the
-    rounding stream drawn exactly once for the 'rr' schemes."""
+    pallas_call per fused op, after the fit's own for ORQ (zero under the
+    reference oracle), and the rounding stream drawn exactly once for the
+    'rr' schemes."""
     names = [n for n in (schemes or all_methods())
              if not make_quantizer(n, bucket_size=d).is_identity]
     key = jax.random.key(11)
@@ -107,17 +114,18 @@ def wire_bundles(schemes: Optional[Sequence[str]] = None, *, nb: int = 5,
     for name in names:
         qz = make_quantizer(name, bucket_size=d)
         draws = 1 if wire._fused_mode(qz) == "rr" else 0
+        fit_enc = kern * (1 + fit_launches(qz))
         enc = jax.make_jaxpr(
             lambda b, m, k: wire.encode(qz, b, m, k))(bkt, mask, key)
         out.append(TraceBundle(
             label=f"wire/{name}/encode", kind="wire_op", closed=enc,
-            meta={"expect_pallas_calls": kern,
+            meta={"expect_pallas_calls": fit_enc,
                   "prng": {"random_bits": draws}}))
         qdq = jax.make_jaxpr(
             lambda b, m, k: wire.qdq(qz, b, m, k))(bkt, mask, key)
         out.append(TraceBundle(
             label=f"wire/{name}/qdq", kind="wire_op", closed=qdq,
-            meta={"expect_pallas_calls": kern,
+            meta={"expect_pallas_calls": fit_enc,
                   "prng": {"random_bits": draws}}))
         # decode input shapes come from the encoder itself, not a
         # hand-maintained words-per-bucket table
@@ -221,9 +229,10 @@ def expected_train_pallas(eng, mesh, pipeline_chunks: int, *,
                           ef: bool = False) -> Optional[int]:
     """Kernel launches one step makes: replicated requant = encode +
     server decode_each + re-encode + worker decode per chunk (4K);
-    fsdp reduce-scatter = encode + decode_mean per chunk (2K). ``ef``
-    adds the replicated error-feedback residual's fused local qdq — one
-    launch per span of every quantized group (``local_qdq_shard``)."""
+    fsdp reduce-scatter = encode + decode_mean per chunk (2K); and for
+    ORQ a level fit before each encode (2K, K). ``ef`` adds the
+    replicated error-feedback residual's fused local qdq, and its fit —
+    per span of every quantized group (``local_qdq_shard``)."""
     if not kernels_enabled():
         return 0
     total = 0
@@ -241,15 +250,16 @@ def expected_train_pallas(eng, mesh, pipeline_chunks: int, *,
                     m = hierarchical.intra_chunk_len(
                         m, _axis_prod(mesh, intra))
                 total += sum(
-                    4 * e._pipeline_k(b - a,
-                                      _axis_prod(mesh, e.axis_names))
+                    (4 + 2 * fit_launches(e.qz))
+                    * e._pipeline_k(b - a, _axis_prod(mesh, e.axis_names))
                     for a, b in e.spans(m))
                 continue
             m = g.size // n_intra if intra else g.size
             workers = eng.fex.n_inter if intra else eng.fex.layout.n_shards
             launches, _ = GradientExchange.rs_stats(
                 e.qz, m, workers, pipeline_chunks)
-            total += launches       # 2K: encode + fused decode per chunk
+            # 2K: encode + fused decode per chunk, and K fits
+            total += launches * (2 + fit_launches(e.qz)) // 2
     else:
         for e, g in zip(eng.pex.engines, eng.pex.layout.groups):
             if e.qz.is_identity:
@@ -260,10 +270,11 @@ def expected_train_pallas(eng, mesh, pipeline_chunks: int, *,
             if intra:
                 m = hierarchical.intra_chunk_len(m, _axis_prod(mesh, intra))
             total += sum(
-                4 * e._pipeline_k(b - a, _axis_prod(mesh, e.axis_names))
+                (4 + 2 * fit_launches(e.qz))
+                * e._pipeline_k(b - a, _axis_prod(mesh, e.axis_names))
                 for a, b in e.spans(m))
             if ef:
-                total += len(e.spans(m))
+                total += (1 + fit_launches(e.qz)) * len(e.spans(m))
     return total
 
 
@@ -517,6 +528,10 @@ def serve_bundles(schemes: Sequence[str] = SERVE_SCHEMES
             a.shape, jnp.bfloat16 if jnp.issubdtype(a.dtype, jnp.floating)
             else a.dtype), params)
     n_attn = sum(1 for s in model.specs if s.kind in ("attn", "attn_local"))
+    # attention layers of the scanned group units: each unit's body is
+    # traced once, however many times its group repeats
+    n_bodies = sum(1 for g in model.groups for s in g.unit
+                   if s.kind in ("attn", "attn_local"))
     out: List[TraceBundle] = []
     for scheme in schemes:
         cfg = ServeConfig(kv_quant=scheme, page_size=4, max_batch=2,
@@ -531,10 +546,12 @@ def serve_bundles(schemes: Sequence[str] = SERVE_SCHEMES
             eng.params, eng.pools, table, pos, seeds, toks)
         quantized = eng.qz is not None
         meta = {
-            # one fused dequant-attend launch per attention layer at the
-            # decode shape; the bf16 escape hatch launches none
+            # per attention body at the decode shape: the fused append
+            # (after ORQ's level fit) and the fused dequant-attend; the
+            # bf16 escape hatch launches none
             "expect_pallas_calls":
-                n_attn if quantized and kernels_enabled() else 0,
+                n_bodies * (2 + fit_launches(eng.qz))
+                if quantized and kernels_enabled() else 0,
             # the paged pools are donated (updated in place)
             "expect_donated": len(jax.tree_util.tree_leaves(eng.pools)),
             "prng": {"random_bits": n_attn if eng._rr else 0},

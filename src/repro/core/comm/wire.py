@@ -13,11 +13,12 @@ this format; the functions here are the single place the encode/decode
 pipeline is defined, shared by ``collectives`` and ``exchange``.
 
 Since PR 5 the default path is the FUSED one-pass kernel pipeline
-(``kernels/fused_*``): ``encode`` lowers to exactly one ``pallas_call``
-(σ-clip -> level search -> random rounding -> bit-pack in one VMEM-tiled
-sweep — only the level FIT stays outside as cheap jnp, and for BinGrad-b
-even the fit fuses), and ``decode``/``decode_mean``/``decode_each`` lower
-to one ``pallas_call`` each (unpack + dequantize [+ average]). The PRNG
+(``kernels/fused_*``): ``encode`` lowers to one ``pallas_call`` for the
+σ-clip -> level search -> random rounding -> bit-pack sweep, after the
+level FIT — for ORQ its own sort-free ``orq_fit`` kernel, for BinGrad-b
+fused into the encode sweep, for the other schemes jnp — and
+``decode``/``decode_mean``/``decode_each`` lower to one ``pallas_call``
+each (unpack + dequantize [+ average]). The PRNG
 bits are threaded in from the same threefry stream as before, so the
 fused path is bit-identical to the multi-pass one (``encode_multipass``
 et al., kept below as the parity baseline) and to the pure-jnp reference
@@ -26,12 +27,16 @@ override — selects. One caveat: the MEAN decode kernels (fused and
 multi-pass alike) accumulate ``val/L`` per worker while the oracle sums
 then scales, so kernel-vs-oracle equality there is exact only when the
 worker count is a power of two (scaling by 2^-k never rounds) and
-float-close otherwise; every other op is exact everywhere.
+float-close otherwise. The ORQ fit is exact in interpret mode; on the
+chip its kernel sums each interval in another order than XLA, so in a
+bucket whose midpoint solve sits on a half-way tie a level may take the
+neighbouring value. Every other op is exact everywhere.
 
 Named scopes (``jax.named_scope``, op metadata only): the level fit runs
-under ``fit`` (``Quantizer.fit``; BinGrad-b's fused fit-and-encode kernel
-too), the rounding stream under ``rbits``, the rest of an encode or local
-quantize->dequantize under ``encode``, and every decode under ``decode``.
+under ``fit`` (``Quantizer.fit`` with its ``orq_fit`` kernel; BinGrad-b's
+fused fit-and-encode kernel too), the rounding stream under ``rbits``,
+the rest of an encode or local quantize->dequantize under ``encode``, and
+every decode under ``decode``.
 """
 from __future__ import annotations
 
@@ -132,7 +137,7 @@ def encode(qz: Quantizer, bkt, mask, key, *, use_kernels: bool = True,
                                       use_kernels=use_kernels)
     if not mode:
         return encode_multipass(qz, bkt, mask, key, use_kernels=use_kernels)
-    levels = qz.fit(bkt, mask)                            # runtime levels
+    levels = qz.fit(bkt, mask, use_kernels=use_kernels)  # runtime levels
     if mode == "rr" and rbits is None:
         rbits = encode_rbits(qz, key, bkt.shape)
     with jax.named_scope("encode"):
@@ -150,7 +155,7 @@ def encode_multipass(qz: Quantizer, bkt, mask, key, *,
     """The PR-1..4 multi-pass encode (fit -> assign kernel -> masked
     select -> pack kernel, each materializing (nb, d) intermediates).
     Kept as the parity/regression baseline for the fused path."""
-    levels = qz.fit(bkt, mask)                            # runtime levels
+    levels = qz.fit(bkt, mask, use_kernels=use_kernels)  # runtime levels
     with jax.named_scope("encode"):
         idx = jnp.where(mask, assign(qz, bkt, levels, key, use_kernels,
                                      mask=mask), 0)
@@ -167,7 +172,7 @@ def qdq(qz: Quantizer, bkt, mask, key, *,
     error-feedback residual hot path — one ``pallas_call``, no idx or
     pack/unpack round-trip (masked-out slots decode to level 0 exactly
     like the multi-pass path)."""
-    levels = qz.fit(bkt, mask)
+    levels = qz.fit(bkt, mask, use_kernels=use_kernels)
     mode = _fused_mode(qz)
     if not mode:
         with jax.named_scope("encode"):

@@ -34,6 +34,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
+from jax import lax
 
 
 class SortedBuckets(NamedTuple):
@@ -89,34 +90,130 @@ def _bucket_max(sb: SortedBuckets) -> jnp.ndarray:
 # ORQ: optimal unbiased multi-level condition (Theorem 1, Eqs. 11/12, Alg. 1)
 # ---------------------------------------------------------------------------
 
-def solve_midpoint(
-    sb: SortedBuckets, bl: jnp.ndarray, br: jnp.ndarray
-) -> jnp.ndarray:
-    """Solve Eq. (12) for b_k given neighbours (b_{k-1}, b_{k+1}) = (bl, br).
+#: lane tile: ``orq_levels`` pads bucket rows to a multiple of it, as the
+#: ``orq_fit`` kernel does, so both reduce over the same widths
+LANES = 128
+#: bisection steps that pin down any int32 key (a range of at most 2^32)
+KEY_STEPS = 32
+
+
+def order_key(x: jnp.ndarray) -> jnp.ndarray:
+    """The order-preserving int32 key of f32 values: ``a < b`` iff
+    ``key(a) < key(b)`` (with -0.0 below +0.0). Its own inverse, read
+    back through :func:`from_key`."""
+    b = lax.bitcast_convert_type(x, jnp.int32)
+    return b ^ ((b >> 31) & 0x7FFFFFFF)
+
+
+def from_key(k: jnp.ndarray) -> jnp.ndarray:
+    return lax.bitcast_convert_type(k ^ ((k >> 31) & 0x7FFFFFFF),
+                                    jnp.float32)
+
+
+def _zero(x: jnp.ndarray) -> jnp.ndarray:
+    """-0.0 -> +0.0, so that the keys order values exactly as ``<`` does."""
+    return jnp.where(x == 0, 0.0, x)
+
+
+def _count(pred: jnp.ndarray) -> jnp.ndarray:
+    """Per row: the number of true entries, (R, d) -> (R, 1) f32 (exact
+    below 2^24; f32 because the TPU's vector reductions are float)."""
+    return jnp.sum(jnp.where(pred, 1.0, 0.0), axis=-1, keepdims=True)
+
+
+class Block(NamedTuple):
+    """An unsorted (R, d) block of buckets, ready for the sort-free solve."""
+
+    v: jnp.ndarray      # (R, d) f32; invalid slots +inf, -0.0 made +0.0
+    k: jnp.ndarray      # (R, d) int32 order keys of ``v``
+
+
+def solve_midpoint(blk: Block, bls, brs) -> list:
+    """Solve Eq. (12) for b_k given neighbours (b_{k-1}, b_{k+1}) = (bl, br),
+    for each pair of ``bls``/``brs`` ((R, 1) columns) at once.
 
     Discrete optimal condition:
         |{b_k <= v <= br}|  =  Σ_{bl<=v<=br} (v - bl) / (br - bl).
 
-    The LHS is a decreasing step function of b_k over the sorted bucket
-    values, so the solution index is closed-form from prefix sums — no
-    iterative search needed (this is the O(d) runtime cost the paper cites).
-    """
-    idx_l = _count_lt(sb, bl)                    # first index with v >= bl
-    idx_r = _count_le(sb, br)                    # one past last index with v <= br
-    cnt_in = (idx_r - idx_l).astype(jnp.float32)  # #values in [bl, br]
-    sum_in = _take(sb.psum, idx_r) - _take(sb.psum, idx_l)
-    width = br - bl
-    safe_w = jnp.where(width > 0, width, 1.0)
-    rhs = (sum_in - bl * cnt_in) / safe_w        # target count in [b_k, br]
-    # count{v in [b, br]} = idx_r - j  where j = first index with v >= b.
-    j = jnp.round(idx_r.astype(jnp.float32) - rhs).astype(jnp.int32)
-    j = jnp.clip(j, idx_l, jnp.maximum(idx_r - 1, idx_l))
-    b = _take(sb.v, jnp.clip(j, 0, sb.v.shape[-1] - 1))
-    b = jnp.where(jnp.isfinite(b), b, 0.0)
-    mid = 0.5 * (bl + br)
-    # Degenerate interval (no data inside, or zero width): bisect.
-    b = jnp.where((cnt_in > 0) & (width > 0), b, mid)
-    return jnp.clip(b, jnp.minimum(bl, br), jnp.maximum(bl, br))
+    The LHS is a decreasing step function of b_k over the bucket's values
+    in ascending order, so the solution is the j-th smallest value, with
+    j = round(#(v <= br) - RHS): the O(d) rule the paper cites. Nothing is
+    sorted: the counts and the sum of the values in [bl, br] are masked
+    reductions, and the j-th smallest value is found by bisection on the
+    int32 order key, bounded by the keys of bl and br. The pairs share the
+    bisection loop, one count per pair per step."""
+    k = blk.k
+    bls = [_zero(bl) for bl in bls]
+    brs = [_zero(br) for br in brs]
+    kls = [order_key(bl) for bl in bls]
+    krs = [order_key(br) for br in brs]
+    stats = []
+    for bl, br, kl, kr in zip(bls, brs, kls, krs):
+        idx_l = _count(k < kl)                   # #(v < bl)
+        idx_r = _count(k <= kr)                  # #(v <= br)
+        cnt_in = idx_r - idx_l                   # #values in [bl, br]
+        sum_in = jnp.sum(jnp.where((k >= kl) & (k <= kr), blk.v, 0.0),
+                         axis=-1, keepdims=True)
+        width = br - bl
+        safe_w = jnp.where(width > 0, width, 1.0)
+        rhs = (sum_in - bl * cnt_in) / safe_w    # target count in [b_k, br]
+        # count{v in [b, br]} = idx_r - j  where j = #(v < b)
+        j = jnp.round(idx_r - rhs)               # half to even
+        j = jnp.clip(j, idx_l, jnp.maximum(idx_r - 1.0, idx_l))
+        stats.append((cnt_in, width, j + 1.0))
+
+    def step(_, bounds):
+        # the smallest key x with #(key <= x) >= j + 1: the j-th smallest
+        out = []
+        for (lo, hi), (_, _, want) in zip(bounds, stats):
+            mid = (lo & hi) + ((lo ^ hi) >> 1)   # floor((lo + hi) / 2)
+            ok = _count(k <= mid) >= want
+            out.append((jnp.where(ok, lo, mid + 1), jnp.where(ok, mid, hi)))
+        return tuple(out)
+
+    bounds = lax.fori_loop(0, KEY_STEPS, step, tuple(zip(kls, krs)))
+    out = []
+    for (_, hi), (cnt_in, width, _), bl, br in zip(bounds, stats, bls, brs):
+        b = from_key(hi)
+        b = jnp.where(jnp.isfinite(b), b, 0.0)
+        mid = 0.5 * (bl + br)
+        # Degenerate interval (no data inside, or zero width): bisect.
+        b = jnp.where((cnt_in > 0) & (width > 0), b, mid)
+        out.append(jnp.clip(b, jnp.minimum(bl, br), jnp.maximum(bl, br)))
+    return out
+
+
+def orq_levels_block(
+    v: jnp.ndarray, valid: jnp.ndarray, K: int, *, refine_iters: int = 0
+) -> jnp.ndarray:
+    """Algorithm 1 on one (R, d) block of buckets: (R, d) values + (R, d)
+    bool validity -> (R, 2^K + 1) ascending levels. The one definition of
+    the fit: :func:`orq_levels` runs it on the whole bucket array, the
+    ``orq_fit`` Pallas kernel on each VMEM tile."""
+    s = 2 ** K + 1
+    v = v.astype(jnp.float32)
+    vm = _zero(jnp.where(valid, v, jnp.inf))
+    blk = Block(v=vm, k=order_key(vm))
+    has = _count(valid) > 0
+    lo = jnp.min(vm, axis=-1, keepdims=True)
+    hi = jnp.max(jnp.where(valid, v, -jnp.inf), axis=-1, keepdims=True)
+    lv = [None] * s
+    # Corollary 1.1: the endpoints are the bucket's min and max
+    lv[0] = jnp.where(has & jnp.isfinite(lo), lo, 0.0)
+    lv[s - 1] = jnp.where(has & jnp.isfinite(hi), hi, 0.0)
+    step = s - 1
+    while step > 1:  # static recursion depth K; a depth's solves share a loop
+        half = step // 2
+        los = list(range(0, s - 1, step))
+        bs = solve_midpoint(blk, [lv[a] for a in los],
+                            [lv[a + step] for a in los])
+        for a, b in zip(los, bs):
+            lv[a + half] = b
+        step = half
+    for _ in range(refine_iters):
+        for k in range(1, s - 1):
+            (lv[k],) = solve_midpoint(blk, [lv[k - 1]], [lv[k + 1]])
+    return jnp.concatenate(lv, axis=-1)
 
 
 def orq_levels(
@@ -131,27 +228,17 @@ def orq_levels(
     ``refine_iters`` > 0 adds coordinate-descent sweeps re-solving every
     interior level against its converged neighbours (beyond-paper refinement
     of the greedy recursion; see EXPERIMENTS.md §Perf for its effect).
+
+    The jnp oracle of the ``orq_fit`` kernel: :func:`orq_levels_block` on
+    the whole array, its rows padded to whole lane tiles as the kernel
+    pads them.
     """
     assert K >= 1
-    s = 2 ** K + 1
-    sb = sort_buckets(bkt, mask)
-    nb = bkt.shape[0]
-    levels = jnp.zeros((nb, s), dtype=jnp.float32)
-    levels = levels.at[:, 0].set(_bucket_min(sb))       # Corollary 1.1
-    levels = levels.at[:, s - 1].set(_bucket_max(sb))   # Corollary 1.1
-    step = s - 1
-    while step > 1:  # static recursion depth K
-        half = step // 2
-        for lo in range(0, s - 1, step):
-            hi = lo + step
-            b = solve_midpoint(sb, levels[:, lo], levels[:, hi])
-            levels = levels.at[:, lo + half].set(b)
-        step = half
-    for _ in range(refine_iters):
-        for k in range(1, s - 1):
-            b = solve_midpoint(sb, levels[:, k - 1], levels[:, k + 1])
-            levels = levels.at[:, k].set(b)
-    return levels
+    d = bkt.shape[-1]
+    pad = -(-d // LANES) * LANES - d
+    bkt = jnp.pad(bkt.astype(jnp.float32), ((0, 0), (0, pad)))
+    mask = jnp.pad(mask.astype(bool), ((0, 0), (0, pad)))
+    return orq_levels_block(bkt, mask, K, refine_iters=refine_iters)
 
 
 def optimality_residual(

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.core import buckets as B
 from repro.core import clipping, encode, levels as L, rounding as R
+from repro.kernels import ops
 
 
 class QuantizedTensor(NamedTuple):
@@ -78,7 +79,11 @@ class Quantizer:
 
     # ------------------------------------------------------------------
     @jax.named_scope("fit")
-    def fit(self, bkt: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+    def fit(self, bkt: jnp.ndarray, mask: jnp.ndarray, *,
+            use_kernels: bool = False) -> jnp.ndarray:
+        """Per-bucket level tables. ``use_kernels`` runs the ORQ solve as
+        the ``orq_fit`` Pallas kernel; off (its jnp oracle), and for every
+        other scheme, the fit is plain jnp."""
         if self.clip_c is not None and self.method not in ("fp",):
             bkt = clipping.sigma_clip(bkt, mask, self.clip_c)
         m = self.method
@@ -86,7 +91,8 @@ class Quantizer:
             K = (self.num_levels - 1).bit_length() - 1
             assert 2 ** K + 1 == self.num_levels, (
                 f"ORQ needs s = 2^K + 1, got {self.num_levels}")
-            return L.orq_levels(bkt, mask, K, refine_iters=self.refine_iters)
+            return ops.orq_fit(bkt, mask, K, refine_iters=self.refine_iters,
+                               use_kernels=use_kernels)
         if m == "bingrad_pb":
             b1 = L.bingrad_pb_b1(bkt, mask)
             return jnp.stack([-b1, b1], axis=-1)

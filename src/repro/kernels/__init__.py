@@ -6,6 +6,7 @@
 #                     clip->round->decode (qdq_fused, the EF residual path)
 #   fused_bingrad.py  fully-fused BinGrad-b (b0 search + levels + 1-bit pack)
 #   fused_decode.py   ONE-pass unpack->dequant->average / per-worker decode
+#   orq_fit.py        the ORQ level fit (Alg. 1) with no sort, one pass per tile
 #   quant_rr.py, bitpack.py, dequant_avg.py, bingrad.py
 #                     the multi-pass kernels (PR 1-4 pipeline) — kept as the
 #                     parity baseline and for callers that need bare stages

@@ -2,8 +2,8 @@
 
 BinGrad-b's level fit is moments-only — b₀ = mean(G), then the
 conditional means below/above b₀ (Eq. 17), optionally iterated to the
-2-means fixed point — so unlike ORQ (which needs a per-bucket sort) the
-WHOLE scheme fuses: one VMEM-tiled sweep computes the b₀ search, the
+2-means fixed point — so unlike ORQ (whose seven bisections per bucket
+run as a kernel of their own, ``orq_fit.py``) the WHOLE scheme fuses: one VMEM-tiled sweep computes the b₀ search, the
 (b₋₁, b₁) level table, the threshold assignment at the level midpoint,
 and the 1-bit pack. The gradient tile is read from HBM once; the only
 writes are the packed (nb, nw) uint32 words and the tiny (nb, 2) level

@@ -23,9 +23,11 @@ Rounding modes (static):
     "bin"   BinGrad-b threshold at the level midpoint (Eq. 17).
     "sign"  scaled SignSGD threshold at 0 (Eq. 13).
 
-The level FIT for the rr schemes stays outside the kernel (ORQ's Alg. 1
-needs a per-bucket sort — cheap jnp, no pallas_call); the BinGrad-b fit
-is moments-only and fuses completely — see ``fused_bingrad.py``.
+The level FIT for the rr schemes stays outside the kernel: ORQ's Alg. 1
+runs as its own sort-free ``pallas_call`` (``orq_fit.py``; its seven
+bisections over each bucket cost far more than this sweep), the others
+as jnp; the BinGrad-b fit is moments-only and fuses completely — see
+``fused_bingrad.py``.
 
 Scheduling (the PR-6 tiling fix):
 

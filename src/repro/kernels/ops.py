@@ -32,6 +32,7 @@ from repro.kernels import fused_bingrad as _fbin
 from repro.kernels import fused_decode as _fdec
 from repro.kernels import fused_encode as _fenc
 from repro.kernels import fused_kv as _fkv
+from repro.kernels import orq_fit as _orq
 from repro.kernels import quant_rr as _quant
 from repro.kernels import ref as _ref
 from repro.utils.env import kernels_enabled  # noqa: F401  (public compat)
@@ -58,6 +59,7 @@ _ref_decode_mean = jax.jit(
     _ref.decode_fused_mean_ref, static_argnames=("d", "bits"))
 _ref_decode_each = jax.jit(
     _ref.decode_fused_each_ref, static_argnames=("d", "bits"))
+_ref_orq_fit = jax.jit(_ref.orq_fit_ref, static_argnums=(2, 3))
 _ref_kv_attend = jax.jit(
     _ref.kv_attend_ref,
     static_argnames=("bits", "kv_heads", "scale", "softcap"))
@@ -126,6 +128,16 @@ def qdq_fused(v, levels, rbits, mask, *, clip_c: Optional[float] = None,
                               mode=mode)
     return _fenc.qdq_fused(v, levels, rbits, mask, s=levels.shape[-1],
                            clip_c=clip_c, mode=mode, interpret=_interpret())
+
+
+def orq_fit(v, mask, K: int, *, refine_iters: int = 0,
+            use_kernels: bool = True):
+    """ORQ level fit (Algorithm 1, no sort) in ONE pallas_call: (nb, d)
+    values + (nb, d) mask -> (nb, 2^K + 1) f32 levels."""
+    if not _use(use_kernels):
+        return _ref_orq_fit(v, mask, K, refine_iters)
+    return _orq.orq_fit(v, mask, K=K, refine_iters=refine_iters,
+                        interpret=_interpret())
 
 
 def encode_bingrad(v, mask, *, clip_c: Optional[float] = None,

@@ -75,6 +75,14 @@ def unpack_ref(words: jnp.ndarray, bits: int, d: int) -> jnp.ndarray:
     return encode.unpack(words, bits, d)
 
 
+def orq_fit_ref(v: jnp.ndarray, mask: jnp.ndarray, K: int,
+                refine_iters: int = 0) -> jnp.ndarray:
+    """Oracle for kernels.orq_fit.orq_fit."""
+    from repro.core import levels
+
+    return levels.orq_levels(v, mask, K, refine_iters=refine_iters)
+
+
 # ---------------------------------------------------------------------------
 # fused-pipeline oracles (the legacy multi-pass compositions)
 # ---------------------------------------------------------------------------

@@ -161,8 +161,8 @@ class TestQdqParity:
 
 class TestJaxprOnePallasCall:
     """PR-5 acceptance: the fused path lowers to exactly ONE pallas_call
-    per encode/decode; the multi-pass path kept >= 2; the reference
-    oracle has none."""
+    per encode/decode, after ORQ's level-fit kernel (``orq_fit``); the
+    multi-pass path kept >= 2; the reference oracle has none."""
 
     @pytest.fixture(autouse=True)
     def _kernels_on(self, monkeypatch):
@@ -181,7 +181,9 @@ class TestJaxprOnePallasCall:
     @pytest.mark.parametrize("name", ["orq-9", "terngrad-clip", "bingrad-b",
                                       "signsgd"])
     def test_encode_single_pallas_call(self, name):
-        _expect_pallas(self._encode_jaxpr(_qz(name, 37), True), 1)
+        qz = _qz(name, 37)
+        _expect_pallas(self._encode_jaxpr(qz, True),
+                       1 + (qz.method == "orq"))
 
     def test_encode_ref_has_none(self):
         _expect_pallas(self._encode_jaxpr(_qz("orq-9", 37), False), 0)
@@ -209,7 +211,7 @@ class TestJaxprOnePallasCall:
         bkt, mask = _buffers(5, 37)
         closed = jax.make_jaxpr(
             lambda b, m, k: wire.qdq(qz, b, m, k))(bkt, mask, KEY)
-        _expect_pallas(closed, 1)
+        _expect_pallas(closed, 2)                     # orq_fit + qdq_fused
 
 
 class TestUseKernelsEnv:
@@ -246,7 +248,7 @@ class TestUseKernelsEnv:
         monkeypatch.setenv("REPRO_USE_KERNELS", "0")
         assert trace() == 0
         monkeypatch.setenv("REPRO_USE_KERNELS", "1")
-        assert trace() == 1
+        assert trace() == 2                           # orq_fit + encode
         monkeypatch.setenv("REPRO_USE_KERNELS", "bogus")
         with pytest.raises(ValueError, match="REPRO_USE_KERNELS"):
             trace()

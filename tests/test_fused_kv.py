@@ -212,7 +212,7 @@ class TestJaxprPins:
         closed = jax.make_jaxpr(
             lambda k, v: append_kv(qz, k, v, rb))(
                 jnp.zeros((4, D)), jnp.zeros((4, D)))
-        self._expect_pallas(closed, 1)
+        self._expect_pallas(closed, 1 + (qz.method == "orq"))  # + orq_fit
 
     def test_attend_single_pallas_call(self):
         B, T, C, H = 2, 1, 8, 4

@@ -12,13 +12,17 @@ not happen while any module is imported. Where it cannot be described
 the fixture skips. The persistent compile cache is off around these
 compiles (a described-chip entry cannot be read back without the chip).
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_config
-from repro.kernels import fused_bingrad, fused_decode, fused_encode, fused_kv
+from repro.kernels import (fused_bingrad, fused_decode, fused_encode,
+                           fused_kv, orq_fit)
 
 CFG = get_config("lm-100m")
 NB, D = 4096, 2048                       # gradient buckets x bucket width
@@ -158,3 +162,61 @@ def test_decode_attend(spec, bits, T):
         spec((KV_BATCH, KV_CONTEXT, nw), jnp.uint32),
         spec((KV_BATCH, KV_CONTEXT, s), jnp.float32),
         spec((KV_BATCH, T, KV_CONTEXT), jnp.bool_))
+
+
+@pytest.mark.parametrize("K,d", [(2, D), (3, D), (4, D), (3, 1000)])
+def test_orq_fit(spec, K, d):
+    """The sort-free level fit of orq-5, orq-9 and orq-17, and of a group
+    narrower than a bucket."""
+    _assert_mosaic(
+        lambda v, m: orq_fit.orq_fit(v, m, K=K, interpret=False),
+        spec((NB, d), jnp.float32), spec((NB, d), jnp.bool_))
+
+
+def test_one_chip_step_fits_levels_without_a_sort(topo, monkeypatch):
+    """The one-chip replicated orq-9 step with error feedback, compiled:
+    the EF residual's fit and phase 1's fit the same buckets and compile
+    to one ``orq_fit`` kernel, phase 2's to the other; both under the
+    exchange's ``fit`` scope, where the benchmark's ``level_fit_ms`` reads
+    them; and no sort is left in any fit."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs.base import get_smoke_config
+    from repro.core import QuantConfig
+    from repro.models import LM
+    from repro.optim.schedule import constant_lr
+    from repro.train import TrainConfig, make_train_step
+    from repro.train.step import init_state
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    model = LM(get_smoke_config("lm-100m"))
+    tcfg = TrainConfig(policy=QuantConfig(name="orq-9", bucket_size=512),
+                       mode="replicated", error_feedback=True, momentum=0.9)
+    rep = NamedSharding(mesh, P())
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(lambda k: init_state(model, mesh, tcfg, k),
+                       jax.random.key(0)))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (2, 33), jnp.int32, sharding=NamedSharding(mesh, P("data")))}
+    step, _ = make_train_step(model, mesh, tcfg, constant_lr(0.01))
+    text = step.lower(state, batch, jax.random.key(0)).compile().as_text()
+
+    def path(line):
+        m = re.search(r'op_name="([^"]*)"', line)
+        return m.group(1).split("/") if m else []
+
+    def in_fit(segs):
+        return "exchange" in segs and "fit" in segs[segs.index("exchange"):]
+
+    fits = [path(ln) for ln in text.splitlines()
+            if re.match(r"\s*(ROOT )?%orq_fit(\.\d+)? = ", ln)
+            and "custom-call(" in ln]
+    assert len(fits) == 2, fits
+    assert all(in_fit(p) for p in fits), fits
+    assert sorted(p[p.index("exchange") + 1] for p in fits) == [
+        "reduce", "requantize"]
+    sorts = [path(ln) for ln in text.splitlines() if " sort(" in ln]
+    assert not [p for p in sorts if "fit" in p], sorts
